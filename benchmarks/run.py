@@ -23,8 +23,11 @@ import pathlib
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="skip kernel microbenches")
     ap.add_argument(

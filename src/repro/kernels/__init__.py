@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the serving hot path (attention) and the SSD scan.
+
+Each kernel package holds ``kernel.py`` (the ``pallas_call``), ``ops.py``
+(the drop-in wrapper callers use) and ``ref.py`` (a pure-jnp oracle).
+"""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_default() -> bool:
+    """Pallas interpret mode runs only where the backend is the CPU: on an
+    accelerator the kernel is always compiled, never silently interpreted."""
+    return jax.default_backend() == "cpu"

@@ -1,10 +1,14 @@
-"""Jit'd public wrapper: pads head_dim/seq to hardware-aligned blocks and
-dispatches to the Pallas kernel (interpret on CPU, compiled on TPU)."""
+"""Drop-in wrapper: (B, S, H, D) attention layout -> the head-major kernel,
+padding the sequence dims to whole blocks (interpret on CPU, compiled on
+TPU)."""
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.prefill_attention.kernel import flash_prefill_attention
 
 
@@ -29,23 +33,24 @@ def prefill_attention(
     logit_cap: float = 0.0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Drop-in attention: (B,Sq,Hq,Dh) x (B,Skv,Hkv,Dh) -> (B,Sq,Hq,Dh)."""
-    b, sq, hq, dh = q.shape
-    skv = k.shape[1]
-    bq = min(block_q, max(8, sq))
-    bk = min(block_k, max(8, skv))
-    # align seq dims to blocks; padded kv is masked via kv_len, padded q rows
-    # are sliced off
-    qp = _pad_to(q, 1, bq)
-    kp = _pad_to(k, 1, bk)
-    vp = _pad_to(v, 1, bk)
-    # padded q rows need positions that keep them masked-safe (attend to pos 0)
-    pos_pad = _pad_to(q_pos.astype(jnp.int32), 1, bq)
+    """Drop-in attention: (B,Sq,Hq,Dh) x (B,Skv,Hkv,Dh) -> (B,Sq,Hq,Dh).
+
+    A sequence no longer than its block is taken whole (a block equal to
+    the array dimension always tiles); longer ones are padded to whole
+    blocks — padded kv is masked via kv_len, padded q rows are sliced off.
+    """
+    sq, skv = q.shape[1], k.shape[1]
+    bq = sq if sq <= block_q else block_q
+    bk = skv if skv <= block_k else block_k
+    qh = _pad_to(q.transpose(0, 2, 1, 3), 2, bq)
+    kh = _pad_to(k.transpose(0, 2, 1, 3), 2, bk)
+    vh = _pad_to(v.transpose(0, 2, 1, 3), 2, bk)
+    pos = _pad_to(q_pos.astype(jnp.int32), 1, bq)
     out = flash_prefill_attention(
-        qp, kp, vp, pos_pad, kv_len,
-        scale=scale, logit_cap=logit_cap,
-        block_q=bq, block_k=bk, interpret=interpret,
+        qh, kh, vh, pos, kv_len,
+        scale=scale, logit_cap=logit_cap, block_q=bq, block_k=bk,
+        interpret=interpret_default() if interpret is None else interpret,
     )
-    return out[:, :sq]
+    return out[:, :, :sq].transpose(0, 2, 1, 3)

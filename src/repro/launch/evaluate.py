@@ -28,6 +28,7 @@ import json
 import sys
 from typing import List, Optional
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.policies import (
     available_autoscaler_policies,
     available_deflection_policies,
@@ -176,6 +177,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> dict:
+    enable_compile_cache()
     ap = build_parser()
     args = ap.parse_args(argv)
 

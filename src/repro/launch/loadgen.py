@@ -43,6 +43,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.policies import (
     available_deflection_policies,
     available_policies,
@@ -301,6 +302,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> dict:
+    enable_compile_cache()
     ap = build_parser()
     args = ap.parse_args(argv)
     scenario_kwargs = None

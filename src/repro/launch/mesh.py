@@ -25,12 +25,8 @@ def ensure_host_platform_devices(n: int = 512) -> None:
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults every
-    # axis to Auto anyway, so omit the kwarg when it doesn't exist.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

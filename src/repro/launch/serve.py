@@ -3,6 +3,13 @@
     PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b-smoke \
         --requests 8 [--policy kairos-urgency] [--decode-policy kairos-slack] \
         [--queue-depth 16] [--list-policies]
+    PYTHONPATH=src python -m repro.launch.serve --arch minicpm-2b   # on a TPU
+
+The model runs in its config's own dtype, with random weights from
+``--seed``. The engine size (slots x max_len) is the widest of
+`repro.launch.sizing.SIZES` that fits the device (`choose_size`); prompts
+are 64-768 tokens. ``-smoke`` archs run on the CPU; a full-width arch needs
+the chip.
 
 ``--policy`` / ``--decode-policy`` accept any name registered in
 ``repro.policies`` (the same registry the simulator uses); ``--list-policies``
@@ -12,19 +19,26 @@ are shed and reported in the session metrics.
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core.request import Request, SLOSpec
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.sizing import choose_size, describe
 from repro.models import build_model
 from repro.policies import available_policies
 from repro.serving.engine import DisaggServer, EngineConfig
 from repro.serving.session import ServeSession
 
 
+PROMPT_RANGE = (64, 768)  # prompt tokens, inclusive
+
+
 def main() -> None:
+    enable_compile_cache()
     pol = available_policies()
     ap = argparse.ArgumentParser(
         description="Disaggregated serving demo (policies from repro.policies)"
@@ -52,7 +66,7 @@ def main() -> None:
         "--list-policies", action="store_true",
         help="print registered policies and exit",
     )
-    ap.add_argument("--chunk-size", type=int, default=16)
+    ap.add_argument("--chunk-size", type=int, default=256)
     ap.add_argument("--ttft-slo", type=float, default=60.0)
     ap.add_argument("--tpot-slo", type=float, default=5.0)
     ap.add_argument("--seed", type=int, default=0)
@@ -63,14 +77,21 @@ def main() -> None:
             print(f"{side}: {', '.join(names)}")
         return
 
-    cfg = get_config(args.arch).replace(dtype="float32")
+    cfg = get_config(args.arch)
     model = build_model(cfg)
-    params = model.init(jax.random.key(0))
+    params = jax.jit(model.init)(jax.random.key(args.seed))
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}; "
+          f"{cfg.name} in {cfg.dtype}")
+    slots, max_len, size = choose_size(
+        model, args.requests, PROMPT_RANGE[1] + args.max_out, dev, chunk=args.chunk_size
+    )
+    print(describe(size, args.requests))
     rng = np.random.default_rng(args.seed)
 
     reqs = []
     for i in range(args.requests):
-        n = int(rng.choice([6, 10, 16, 40], p=[0.4, 0.3, 0.2, 0.1]))
+        n = int(rng.integers(PROMPT_RANGE[0], PROMPT_RANGE[1] + 1))
         prompt = list(map(int, rng.integers(2, cfg.vocab_size, n)))
         reqs.append(
             (
@@ -81,11 +102,14 @@ def main() -> None:
         )
 
     ecfg = EngineConfig(
-        max_slots=8, max_len=128, chunk_size=args.chunk_size,
+        max_slots=slots, max_len=max_len, chunk_size=args.chunk_size,
         prefill_policy=args.policy, decode_policy=args.decode_policy,
         admission_queue_depth=args.queue_depth or None,
     )
-    server = DisaggServer(model, params, ecfg)
+    server = DisaggServer(model, params, ecfg, device=dev)
+    t0 = time.perf_counter()
+    server.warmup()
+    print(f"compile (warm-up of every step shape): {time.perf_counter() - t0:.2f} s")
 
     # drive the streaming session directly (what serve() wraps) so the
     # admission metrics stay in hand

@@ -227,11 +227,13 @@ def attention(
 ) -> jax.Array:
     """Dispatch between naive / blockwise / Pallas-kernel attention.
 
-    impl="pallas" routes to the flash kernels (TPU target; interpret mode on
-    CPU). Only the kernel-supported case qualifies — causal, no window, no
-    packed segments, static-int window — otherwise falls through to the jnp
-    paths. Packed-segment masks force the naive path (segments only occur in
-    the CPU engine where sequences are short).
+    impl="pallas" routes to the flash kernels, compiled on an accelerator
+    and interpreted only on the CPU (`repro.kernels.interpret_default`): a
+    single query token (decode) goes to the flash-decode kernel, anything
+    longer to the chunked-prefill kernel. Only the kernel-supported case
+    qualifies — causal, no window, no packed segments — otherwise falls
+    through to the jnp paths. Packed-segment masks force the naive path
+    (segments only occur in the CPU engine where sequences are short).
     """
     sq, skv = q.shape[1], k.shape[1]
     if (
@@ -240,12 +242,23 @@ def attention(
         and causal
         and not _window_active(window)
     ):
-        from repro.kernels.prefill_attention.ops import prefill_attention
-
         kv_valid_eff = (
             kv_valid if kv_valid is not None
             else jnp.full((q.shape[0],), skv, jnp.int32)
         )
+        if sq == 1:
+            # one token at position p attends to [0, p]: with the cache's
+            # valid length p + 1 (what decode passes) causality is the
+            # length mask the decode kernel applies
+            from repro.kernels.decode_attention.ops import decode_attention
+
+            kv_len = jnp.minimum(kv_valid_eff, q_pos[:, 0] + 1)
+            out = decode_attention(
+                q[:, 0], k, v, kv_len, scale=scale, logit_cap=logit_cap
+            )
+            return out[:, None]
+        from repro.kernels.prefill_attention.ops import prefill_attention
+
         return prefill_attention(
             q, k, v, q_pos, kv_valid_eff, scale=scale, logit_cap=logit_cap
         )

@@ -5,7 +5,7 @@
   logits = model.forward_train(params, batch)       # batch: dict
   logits, kv = model.prefill(params, batch)
   logits, cache = model.decode(params, tokens, positions, cache)
-  cache = model.init_cache(batch, max_len)          # zeros, allocated
+  cache = model.init_cache(batch, max_len[, device]) # zeros, allocated
   spec  = cache_struct(cfg, batch, max_len)         # ShapeDtypeStructs only
 """
 from __future__ import annotations
@@ -53,10 +53,11 @@ class Model:
             return encdec.init_params(self.cfg, key)
         return transformer.init_params(self.cfg, key)
 
-    def init_cache(self, batch: int, max_len: int) -> Dict:
+    def init_cache(self, batch: int, max_len: int, device=None) -> Dict:
+        """Zeroed cache, allocated on `device` (the default device if None)."""
         return _materialize(
             _cache_shapes(self.cfg, batch, max_len),
-            lambda sd: jnp.zeros(sd[0], dtype_of(sd[1])),
+            lambda sd: jnp.zeros(sd[0], dtype_of(sd[1]), device=device),
         )
 
     # ----------------------------------------------------------------- train

@@ -92,9 +92,10 @@ def _init_ssm_layer(key, cfg: ModelConfig, dtype) -> Dict:
 
 
 def _stack_layers(key, n: int, init_fn):
-    keys = jax.random.split(key, n)
-    layers = [init_fn(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+    """Init `n` layers directly in stacked (n, ...) form: vmapping over the
+    keys draws the same values as n separate calls, without holding every
+    layer's leaves beside a stacked copy (two full copies of the trunk)."""
+    return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
 def init_params(cfg: ModelConfig, key) -> Dict:

@@ -1,0 +1,7 @@
+"""Whole decode step against the chip's peak, in percent: model FLOPs of
+the traced steps' live lanes over their device time times the peak FLOP/s."""
+from _programs import DECODE, mfu
+
+
+def read(run):
+    return mfu(run, DECODE, run.decode_work)

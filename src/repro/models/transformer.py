@@ -200,13 +200,10 @@ def attn_block(
             ck = jnp.where(hit, k[:, 0][:, None], ck)
             cv = jnp.where(hit, v[:, 0][:, None], cv)
         else:
-            start = positions[:, 0]
-            ck = jax.vmap(
-                lambda c, kk, st: jax.lax.dynamic_update_slice(c, kk, (st, 0, 0))
-            )(ck, k, start)
-            cv = jax.vmap(
-                lambda c, vv, st: jax.lax.dynamic_update_slice(c, vv, (st, 0, 0))
-            )(cv, v, start)
+            with jax.named_scope("cache_write"):
+                write = jax.vmap(partial(_write_chunk, rows=s))
+                ck = write(ck, k, positions[:, 0])
+                cv = write(cv, v, positions[:, 0])
         out = attention(
             q, ck, cv, positions, kv_valid,
             window=window, causal=True, logit_cap=cfg.attn_logit_softcap,
@@ -215,6 +212,25 @@ def attn_block(
         new_kv = (ck, cv)
     out = out.reshape(b, s, cfg.num_heads * cfg.resolved_head_dim)
     return jnp.einsum("bse,ed->bsd", out, p["wo"]), new_kv
+
+
+def _write_chunk(cache: jax.Array, new: jax.Array, start: jax.Array, *, rows: int) -> jax.Array:
+    """Write one request's chunk (rows, ...) into its cache (M, ...) so that
+    chunk row j lands at cache row start + j.
+
+    ``dynamic_update_slice`` clamps a block that would run past the cache's
+    end, which would land the whole chunk early, over earlier rows. So the
+    block is written at ``at = min(start, M - rows)``: the chunk shifted by
+    ``start - at`` rows within it, and the cache's own rows below that
+    shift kept. Chunk rows past the end are pad and drop out.
+    """
+    at = jnp.minimum(start, cache.shape[0] - rows)
+    shift = start - at
+    corner = (at, *[0] * (cache.ndim - 1))
+    old = jax.lax.dynamic_slice(cache, corner, new.shape)
+    keep = jnp.expand_dims(jnp.arange(rows) < shift, tuple(range(1, new.ndim)))
+    block = jnp.where(keep, old, jnp.roll(new, shift, axis=0))
+    return jax.lax.dynamic_update_slice(cache, block, corner)
 
 
 def _ffn(layer: Dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:

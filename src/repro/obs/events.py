@@ -11,19 +11,35 @@ The taxonomy is the request lifecycle every backend shares:
     DEFLECT         disagg fleet: prefill deflected onto a decode worker
     ROUTE           router: replica chosen for the request
     PREFILL_START   first prefill chunk of the request begins
+    PREFILL_CHUNK   one prefill chunk is run for the request (``data``:
+                    ``start`` offset, ``take`` tokens, ``chunk_size``)
     PREFILL_END     prompt fully prefilled; first token exists
     HANDOFF_QUEUED  prefill→decode KV handoff enters the queue
     HANDOFF_START   handoff occupies an in-flight transfer slot
                     (``data["ready_at"]`` prices the wire time)
     HANDOFF_ATTACH  KV landed in a decode slot; decoding begins
     DECODE_STEP     one engine decode step (rid = -1: a pool-level event;
-                    ``data``: batch, step_time, active, tpot_budget)
+                    ``data``: batch, step_time, active, tpot_budget; from a
+                    live engine also ``bucket`` (lanes of the padded batch),
+                    ``positions`` (each live lane's position) and the
+                    host's ``launch_s`` / ``sync_s`` of the step)
     TOKEN           one token produced for a request
     CANCEL          client withdrew the request (``data["stage"]`` says
                     where it was caught); terminal, Phase.CANCELLED
     DONE            request completed; terminal
     FAIL            engine crash containment tore the request down
                     (async frontend stepper crash); terminal
+    ROUND           one round of a live session (rid = -1; t = the round's
+                    ``now``; ``data``: host seconds ``wall_s`` to the
+                    round's end, ``select_s`` in both schedulers' selects,
+                    ``engine_s`` in the prefill, admit/attach and decode
+                    engine calls)
+
+PREFILL_CHUNK, ROUND and the engine fields of DECODE_STEP are counters of
+host work: durations and counts read with the clock's observation-free
+``peek``, never a new ``monotonic`` read, so they need no mapping onto the
+profiler's clock and cannot perturb a ManualClock run. The simulator has
+no host rounds and emits no ROUND.
 
 Fleet-control events (rid = -1 except RESTORE; `repro.serving.fleetctl`):
 
@@ -64,6 +80,7 @@ class EventType(str, enum.Enum):
     DEFLECT = "deflect"
     ROUTE = "route"
     PREFILL_START = "prefill_start"
+    PREFILL_CHUNK = "prefill_chunk"
     PREFILL_END = "prefill_end"
     HANDOFF_QUEUED = "handoff_queued"
     HANDOFF_START = "handoff_start"
@@ -73,6 +90,7 @@ class EventType(str, enum.Enum):
     CANCEL = "cancel"
     DONE = "done"
     FAIL = "fail"
+    ROUND = "round"
     REPLICA_DOWN = "replica_down"
     REPLICA_UP = "replica_up"
     RESTORE = "restore"
@@ -90,8 +108,8 @@ class Event:
     """One trace record. ``t`` is *virtual* time from the emitter's injected
     Clock (sim cost-model time for the simulator) — never host wall time.
     ``pool`` is the emitting track: "engine:0", "replica:1", "prefill:0",
-    "decode:1", or "sim". ``rid`` is -1 for pool-level events
-    (DECODE_STEP)."""
+    "decode:1", "fleet" or "sim". ``rid`` is -1 for pool-level events
+    (DECODE_STEP, ROUND)."""
 
     type: EventType
     t: float

@@ -12,7 +12,8 @@ Two formats off one stream:
     scheduler track), and each request renders as three slices — prefill,
     handoff, decode — plus a TTFT flow arrow from its SUBMIT instant to its
     first TOKEN. Queue depth and in-flight transfers render as counter
-    tracks.
+    tracks; each session ROUND as a slice of its scheduler track, each
+    DECODE_STEP and PREFILL_CHUNK as an instant with its counters.
 
 ``write_trace`` dispatches on the path suffix: ``.jsonl`` writes the event
 log, anything else the Chrome JSON. Timestamps are emitted in microseconds
@@ -193,6 +194,24 @@ def chrome_trace(events: Sequence[Event]) -> Dict[str, Any]:
                     name="decode_step", cat="engine", ph="i",
                     ts=_us(ev.t), pid=pid, tid=tid, s="p",
                     args=dict(ev.data),
+                )
+            )
+        elif ev.type is EventType.PREFILL_CHUNK:
+            body.append(
+                dict(
+                    name=f"prefill_chunk r{ev.rid}", cat="engine", ph="i",
+                    ts=_us(ev.t), pid=pid, tid=tid, s="t",
+                    args=dict(rid=ev.rid, **ev.data),
+                )
+            )
+        elif ev.type is EventType.ROUND:
+            # the round as a slice of the scheduler track, its host seconds
+            # in the args
+            body.append(
+                dict(
+                    name="round", cat="engine", ph="X", ts=_us(ev.t),
+                    dur=max(0.0, _us(ev.data.get("wall_s", 0.0))),
+                    pid=pid, tid=tid, args=dict(ev.data),
                 )
             )
         # queue-depth gauge: sessions sample it into ADMIT / PREFILL_END data

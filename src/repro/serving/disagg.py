@@ -45,10 +45,12 @@ tests/test_disagg.py).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.request import Phase, Request
 from repro.obs.events import EventType, TraceRecorder
@@ -150,6 +152,9 @@ class _FleetClock:
 
     def _now(self) -> float:
         return self.servers[0]._now()
+
+    def peek_now(self) -> float:
+        return self.servers[0].peek_now()
 
     def reset_clock(self) -> None:
         """Re-zero virtual time for the whole fleet via ONE reset — N
@@ -560,18 +565,21 @@ class DisaggSession:
             )
 
     # ---------------------------------------------------------------- step
+    @partial(annotate_function, name="session.step")
     def step(self) -> List[int]:
         """Advance the fleet one round; returns rids completed this round.
 
         Per-worker stage bodies mirror `ServeSession.step` *read-for-read*
         (same clock calls in the same order per worker) — the basis of the
         1P:1D bit-parity contract. Do not add or reorder clock reads here
-        without updating that test.
+        without updating that test. The spans and the round's host-time
+        counters are `ServeSession.step`'s too (`peek_now` reads only).
         """
         ecfg = self.ecfg
         clock = self.server.clock
         completed: List[int] = []
         now = self.server._now()
+        select_s = engine_s = 0.0  # host seconds in the selects and the engines
 
         # ---- prefill stage: the prefill pool, then deflected prompts on
         # decode workers (a deflected prefill runs the same chunked loop,
@@ -582,17 +590,29 @@ class DisaggSession:
                 continue
             srv = w.server
             pq = [lr.req for lr in w.queue]
-            sel = srv.prefill_sched.select(pq, now, srv.mu.mu, ecfg.chunk_size)
+            t = srv.peek_now()
+            with TraceAnnotation("prefill_sched.select"):
+                sel = srv.prefill_sched.select(pq, now, srv.mu.mu, ecfg.chunk_size)
+            select_s += srv.peek_now() - t
             t0 = clock.monotonic()
             total = 0
             for req, take in sel:
                 lr = next(l for l in w.queue if l.req is req)
-                if trc is not None and req.prefilled_tokens == 0:
+                if trc is not None:
+                    if req.prefilled_tokens == 0:
+                        trc.emit(
+                            EventType.PREFILL_START, now, rid=req.rid,
+                            tenant=req.tenant, pool=w.label, take=take,
+                        )
                     trc.emit(
-                        EventType.PREFILL_START, now, rid=req.rid,
-                        tenant=req.tenant, pool=w.label, take=take,
+                        EventType.PREFILL_CHUNK, now, rid=req.rid,
+                        tenant=req.tenant, pool=w.label,
+                        start=req.prefix_cached_tokens + req.prefilled_tokens,
+                        take=take, chunk_size=ecfg.chunk_size,
                     )
+                t = srv.peek_now()
                 logits = srv.prefill.run_chunk(lr, take)
+                engine_s += srv.peek_now() - t
                 total += take
                 if logits is not None:
                     fin = srv._now()
@@ -629,7 +649,9 @@ class DisaggSession:
                 continue  # KV still on the wire
             self.inflight.remove(tr)
             lr = tr.lr
+            t = self.server.peek_now()
             tr.dst.server.decode.attach(lr)  # the real slot-to-slot copy
+            engine_s += self.server.peek_now() - t
             lr.req.phase = Phase.DECODE
             lr.req.decode_start = self.server._now()
             tr.dst.active.append(lr)
@@ -653,56 +675,64 @@ class DisaggSession:
             if not w.active:
                 continue
             srv = w.server
-            batch_reqs, _ = srv.decode_sched.select(
-                [l.req for l in w.active], srv._now()
-            )
+            dnow = srv._now()
+            t = srv.peek_now()
+            with TraceAnnotation("decode_sched.select"):
+                batch_reqs, _ = srv.decode_sched.select([l.req for l in w.active], dnow)
+            select_s += srv.peek_now() - t
             batch = [l for l in w.active if l.req in batch_reqs]
             srv._key, sub = jax.random.split(srv._key)
             t0 = clock.monotonic()
+            t = srv.peek_now()
             toks = srv.decode.step(batch, sub)
+            engine_s += srv.peek_now() - t
             step_t = (clock.monotonic() - t0) * ecfg.time_scale
             tend = srv._now()
             srv.decode_sched.observe([l.req for l in batch], step_t)
             if trc is not None and batch:
+                st = srv.decode.last_step
                 trc.emit(
                     EventType.DECODE_STEP, tend, pool=w.label,
                     batch=len(batch), step_time=step_t,
                     active=len(w.active),
                     tpot_budget=min(l.req.slo.tpot for l in batch),
+                    bucket=st.bucket, positions=st.positions,
+                    launch_s=st.launch_s, sync_s=st.sync_s,
                 )
-            for lr, tok in zip(batch, toks, strict=True):
-                r = lr.req
-                tok = int(tok)
-                lr.tokens.append(tok)
-                r.n_generated += 1
-                r.n_decoded += 1
-                r.token_times.append(tend)
-                if trc is not None:
-                    trc.emit(
-                        EventType.TOKEN, tend, rid=r.rid, tenant=r.tenant,
-                        pool=w.label, slot=lr.slot,
-                    )
-                self._emit(r, tok, tend)
-                done = (
-                    tok == ecfg.eos_token
-                    or r.n_generated >= r.output_len
-                    or r.seq_len >= ecfg.max_len - 1
-                )
-                if done:
-                    r.phase = Phase.DONE
-                    r.done_time = tend
-                    slot = lr.slot
-                    srv.decode.release(lr)  # also unpins r.rid's radix hold
-                    self._kv_dst.pop(r.rid, None)
-                    w.active.remove(lr)
-                    self.metrics.completed += 1
-                    self.metrics._bump(self.metrics.completed_by_tenant, r.tenant)
-                    completed.append(r.rid)
+            with TraceAnnotation("session.tokens"):
+                for lr, tok in zip(batch, toks, strict=True):
+                    r = lr.req
+                    tok = int(tok)
+                    lr.tokens.append(tok)
+                    r.n_generated += 1
+                    r.n_decoded += 1
+                    r.token_times.append(tend)
                     if trc is not None:
                         trc.emit(
-                            EventType.DONE, tend, rid=r.rid, tenant=r.tenant,
-                            pool=w.label, slot=slot, n_generated=r.n_generated,
+                            EventType.TOKEN, tend, rid=r.rid, tenant=r.tenant,
+                            pool=w.label, slot=lr.slot,
                         )
+                    self._emit(r, tok, tend)
+                    done = (
+                        tok == ecfg.eos_token
+                        or r.n_generated >= r.output_len
+                        or r.seq_len >= ecfg.max_len - 1
+                    )
+                    if done:
+                        r.phase = Phase.DONE
+                        r.done_time = tend
+                        slot = lr.slot
+                        srv.decode.release(lr)  # also unpins r.rid's radix hold
+                        self._kv_dst.pop(r.rid, None)
+                        w.active.remove(lr)
+                        self.metrics.completed += 1
+                        self.metrics._bump(self.metrics.completed_by_tenant, r.tenant)
+                        completed.append(r.rid)
+                        if trc is not None:
+                            trc.emit(
+                                EventType.DONE, tend, rid=r.rid, tenant=r.tenant,
+                                pool=w.label, slot=slot, n_generated=r.n_generated,
+                            )
 
         # when the only remaining work is KV on the wire, nudge the clock
         # toward the earliest ready_at — same rule as `ServeSession.step`
@@ -713,6 +743,12 @@ class DisaggSession:
         ):
             nxt = min((tr.ready_at for tr in self.inflight), default=now)
             clock.sleep(min(0.001, max(0.0, nxt - self.server._now())))
+        if trc is not None:
+            trc.emit(
+                EventType.ROUND, now, pool="fleet",
+                wall_s=self.server.peek_now() - now, select_s=select_s,
+                engine_s=engine_s,
+            )
         return completed
 
     # ------------------------------------------------------------- metrics

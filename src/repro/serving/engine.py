@@ -21,11 +21,12 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import TYPE_CHECKING, Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.lut import StepTimeLUT
 from repro.core.predictor import PrefillThroughputEstimator
@@ -63,18 +64,27 @@ def _bucket(n: int, buckets: Sequence[int]) -> int:
 _chunk_step = jax.jit(chunk_prefill_step, static_argnums=(4,))
 
 
+# The named scopes label the device ops of each part of a decode step in a
+# profiler trace (the ops' ``tf_op`` / ``long_name``), so a trace says which
+# copies are the KV gather and scatter and which ops are the model.
 @partial(jax.jit, static_argnums=(5,))
 def _slot_step(params, tokens, positions, cache, slot_idx, cfg: ModelConfig):
-    sub = gather_slots(cfg, cache, slot_idx)
-    logits, sub2 = decode_step(params, tokens, positions, cfg, sub)
-    return logits, scatter_slots(cfg, cache, sub2, slot_idx)
+    with jax.named_scope("kv_gather"):
+        sub = gather_slots(cfg, cache, slot_idx)
+    with jax.named_scope("model"):
+        logits, sub2 = decode_step(params, tokens, positions, cfg, sub)
+    with jax.named_scope("kv_scatter"):
+        return logits, scatter_slots(cfg, cache, sub2, slot_idx)
 
 
 @partial(jax.jit, static_argnums=(5,))
 def _page_step(params, tokens, positions, pool, page_idx, cfg: ModelConfig):
-    sub = gather_pages(cfg, pool, page_idx)
-    logits, sub2 = decode_step(params, tokens, positions, cfg, sub)
-    return logits, scatter_pages(cfg, pool, sub2, page_idx)
+    with jax.named_scope("kv_gather"):
+        sub = gather_pages(cfg, pool, page_idx)
+    with jax.named_scope("model"):
+        logits, sub2 = decode_step(params, tokens, positions, cfg, sub)
+    with jax.named_scope("kv_scatter"):
+        return logits, scatter_pages(cfg, pool, sub2, page_idx)
 
 
 def _to(tree: Any, device) -> Any:
@@ -143,6 +153,19 @@ class LiveRequest:
     page_table: Optional[List[int]] = None
 
 
+@dataclass
+class DecodeStepStats:
+    """What one `DecodeEngine.step` did: lanes of the padded batch, each
+    live lane's position, and the host's virtual seconds from entry until
+    the step program and the sampler were dispatched (``launch_s``) and
+    then blocked until the tokens were on the host (``sync_s``)."""
+
+    bucket: int
+    positions: List[int]
+    launch_s: float
+    sync_s: float
+
+
 class PrefillEngine:
     def __init__(self, model: Model, params: Dict, ecfg: EngineConfig, device=None):
         self.model, self.params, self.ecfg = model, params, ecfg
@@ -179,6 +202,7 @@ class PrefillEngine:
             cache[name] = leaf.at[:, :, : len(pages) * ps].set(_to(head, self.device))
         return cache
 
+    @partial(annotate_function, name="prefill.run_chunk")
     def run_chunk(self, lr: LiveRequest, take: int) -> Optional[np.ndarray]:
         """Prefill `take` tokens of lr; returns last logits if prompt done."""
         r = lr.req
@@ -202,9 +226,14 @@ class PrefillEngine:
 
 
 class DecodeEngine:
-    def __init__(self, model: Model, params: Dict, ecfg: EngineConfig, device=None):
+    def __init__(self, model: Model, params: Dict, ecfg: EngineConfig, device,
+                 peek: Callable[[], float]):
         self.model, self.params, self.ecfg = model, params, ecfg
         self.device = device
+        # observation-free clock read (`Clock.peek`) that times each step's
+        # launch and sync into `last_step`; never advances a ManualClock
+        self._peek = peek
+        self.last_step: Optional[DecodeStepStats] = None
         cfg = model.cfg
         # slot ids stay the batch-lane identity in both layouts; in paged
         # mode they charge 0 tokens (the page pool is the capacity) so
@@ -324,6 +353,7 @@ class DecodeEngine:
         # this head link these pages instead of recomputing the KV
         self.prefix.assign_pages(lr.tokens[: r.input_len], table)
 
+    @partial(annotate_function, name="decode.admit")
     def admit(self, lr: LiveRequest) -> bool:
         """Transfer prefill KV into decode capacity (the PD handoff)."""
         if not self.reserve(lr):
@@ -344,19 +374,30 @@ class DecodeEngine:
             lr.slot = None
 
     def step(self, batch: List[LiveRequest], key) -> np.ndarray:
-        """One decode step over the scheduler-chosen sub-batch."""
+        """One decode step over the scheduler-chosen sub-batch; what it did
+        is left in `last_step`."""
         ecfg = self.ecfg
-        bs = _bucket(len(batch), ecfg.decode_buckets)
-        toks = [lr.tokens[-1] for lr in batch] + [0] * (bs - len(batch))
-        pos = [lr.req.seq_len - 1 for lr in batch] + [0] * (bs - len(batch))
-        if self.pages is not None:
-            p, sp = self.pages_per_req, self.scratch_page
-            lanes = [lr.page_table + [sp] * (p - len(lr.page_table)) for lr in batch]
-        else:
-            lanes = [lr.slot for lr in batch]
-        logits = self._run(toks, pos, lanes, bs)
-        toks_out = sample(logits, temperature=ecfg.temperature, key=key)
-        return np.asarray(toks_out)[: len(batch)]
+        n = len(batch)
+        with TraceAnnotation("decode.step"):
+            t0 = self._peek()
+            with TraceAnnotation("decode.launch"):
+                bs = _bucket(n, ecfg.decode_buckets)
+                toks = [lr.tokens[-1] for lr in batch] + [0] * (bs - n)
+                pos = [lr.req.seq_len - 1 for lr in batch] + [0] * (bs - n)
+                if self.pages is not None:
+                    p, sp = self.pages_per_req, self.scratch_page
+                    lanes = [lr.page_table + [sp] * (p - len(lr.page_table)) for lr in batch]
+                else:
+                    lanes = [lr.slot for lr in batch]
+                logits = self._run(toks, pos, lanes, bs)
+                toks_out = sample(logits, temperature=ecfg.temperature, key=key)
+            t1 = self._peek()
+            with TraceAnnotation("decode.sync"):
+                out = np.asarray(toks_out)[:n]
+            t2 = self._peek()
+        scale = ecfg.time_scale
+        self.last_step = DecodeStepStats(bs, pos[:n], (t1 - t0) * scale, (t2 - t1) * scale)
+        return out
 
     def _run(self, toks: List[int], pos: List[int], lanes: List, bs: int) -> jax.Array:
         """Run the jitted step on `lanes` padded to `bs` with scratch lanes
@@ -422,7 +463,10 @@ class DisaggServer:
         # `serve()` call traces without the caller threading a recorder
         self.trace = trace
         self.prefill = PrefillEngine(model, params, ecfg, device)
-        self.decode = DecodeEngine(model, params, ecfg, device)
+        # the engine reads whichever clock the server holds at the time (a
+        # launcher may swap `self.clock` after construction)
+        self.decode = DecodeEngine(model, params, ecfg, device,
+                                   peek=lambda: self.clock.peek())
         self._init_sched_state()
         # transfer pricing shared with the simulator: one formula for both
         # the in-server admission handoff and the fleet's cross-server copy

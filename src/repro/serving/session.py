@@ -36,10 +36,12 @@ See DESIGN.md §session.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.request import Phase, Request
 from repro.obs.events import EventType, TraceRecorder
@@ -294,31 +296,50 @@ class ServeSession:
             self.on_token(req, tok, t)
 
     # ---------------------------------------------------------------- step
+    @partial(annotate_function, name="session.step")
     def step(self) -> List[int]:
-        """Advance the session one round; returns rids completed this round."""
+        """Advance the session one round; returns rids completed this round.
+
+        Each call into a scheduler or an engine sits in a profiler span
+        named for it, and the round's host time is counted with the
+        observation-free `peek_now`, so neither perturbs a ManualClock run.
+        With a recorder attached the round ends in a ROUND event."""
         srv = self.server
         ecfg = self.ecfg
         clock = srv.clock
         completed: List[int] = []
         now = srv._now()
+        select_s = engine_s = 0.0  # host seconds in the selects and the engines
 
         # ---- prefill side ------------------------------------------------
         tr = self.trace
         pq = [lr.req for lr in self.queue]
         if pq:
-            sel = srv.prefill_sched.select(pq, now, srv.mu.mu, ecfg.chunk_size)
+            t = srv.peek_now()
+            with TraceAnnotation("prefill_sched.select"):
+                sel = srv.prefill_sched.select(pq, now, srv.mu.mu, ecfg.chunk_size)
+            select_s += srv.peek_now() - t
             t0 = clock.monotonic()
             total = 0
             for req, take in sel:
                 lr = next(l for l in self.queue if l.req is req)
-                if tr is not None and req.prefilled_tokens == 0:
-                    # first chunk of this request's prefill (t = the round's
-                    # already-read `now`; no extra clock read)
+                if tr is not None:
+                    if req.prefilled_tokens == 0:
+                        # first chunk of this request's prefill (t = the
+                        # round's already-read `now`; no extra clock read)
+                        tr.emit(
+                            EventType.PREFILL_START, now, rid=req.rid,
+                            tenant=req.tenant, pool=self.trace_label, take=take,
+                        )
                     tr.emit(
-                        EventType.PREFILL_START, now, rid=req.rid,
-                        tenant=req.tenant, pool=self.trace_label, take=take,
+                        EventType.PREFILL_CHUNK, now, rid=req.rid,
+                        tenant=req.tenant, pool=self.trace_label,
+                        start=req.prefix_cached_tokens + req.prefilled_tokens,
+                        take=take, chunk_size=ecfg.chunk_size,
                     )
+                t = srv.peek_now()
                 logits = srv.prefill.run_chunk(lr, take)
+                engine_s += srv.peek_now() - t
                 total += take
                 if logits is not None:
                     fin = srv._now()
@@ -373,7 +394,10 @@ class ServeSession:
         for lr in list(self.waiting_adm):
             if lr.transfer_ready_at is not None and now < lr.transfer_ready_at:
                 continue  # KV still on the wire
-            if srv.decode.admit(lr):
+            t = srv.peek_now()
+            ok = srv.decode.admit(lr)
+            engine_s += srv.peek_now() - t
+            if ok:
                 lr.req.phase = Phase.DECODE
                 lr.req.decode_start = srv._now()
                 self.waiting_adm.remove(lr)
@@ -388,61 +412,71 @@ class ServeSession:
 
         # ---- decode side -------------------------------------------------
         if self.active:
-            batch_reqs, _ = srv.decode_sched.select(
-                [l.req for l in self.active], srv._now()
-            )
+            dnow = srv._now()
+            t = srv.peek_now()
+            with TraceAnnotation("decode_sched.select"):
+                batch_reqs, _ = srv.decode_sched.select([l.req for l in self.active], dnow)
+            select_s += srv.peek_now() - t
             batch = [l for l in self.active if l.req in batch_reqs]
             srv._key, sub = jax.random.split(srv._key)
             t0 = clock.monotonic()
+            t = srv.peek_now()
             toks = srv.decode.step(batch, sub)
+            engine_s += srv.peek_now() - t
             step_t = (clock.monotonic() - t0) * ecfg.time_scale
             tend = srv._now()
             srv.decode_sched.observe([l.req for l in batch], step_t)
             if tr is not None and batch:
                 # pool-level step record (rid = -1): the batch the decode
                 # scheduler packed, the engine step time, and the tightest
-                # TPOT budget in the batch — obs/slo.py's budget series
+                # TPOT budget in the batch — obs/slo.py's budget series —
+                # then what the engine did: the padded bucket, each lane's
+                # position, and the host's launch and sync seconds
+                st = srv.decode.last_step
                 tr.emit(
                     EventType.DECODE_STEP, tend, pool=self.trace_label,
                     batch=len(batch), step_time=step_t,
                     active=len(self.active),
                     tpot_budget=min(l.req.slo.tpot for l in batch),
+                    bucket=st.bucket, positions=st.positions,
+                    launch_s=st.launch_s, sync_s=st.sync_s,
                 )
-            for lr, tok in zip(batch, toks, strict=True):
-                r = lr.req
-                tok = int(tok)
-                lr.tokens.append(tok)
-                r.n_generated += 1
-                r.n_decoded += 1
-                r.token_times.append(tend)
-                if tr is not None:
-                    tr.emit(
-                        EventType.TOKEN, tend, rid=r.rid, tenant=r.tenant,
-                        pool=self.trace_label, slot=lr.slot,
-                    )
-                self._emit(r, tok, tend)
-                done = (
-                    tok == ecfg.eos_token
-                    or r.n_generated >= r.output_len
-                    or r.seq_len >= ecfg.max_len - 1
-                )
-                if done:
-                    r.phase = Phase.DONE
-                    r.done_time = tend
-                    slot = lr.slot
-                    srv.decode.release(lr)
-                    if self.prefix_cache is not None:
-                        self.prefix_cache.release(r.rid)  # idempotent unpin
-                    self.active.remove(lr)
-                    self.metrics.completed += 1
-                    self.metrics._bump(self.metrics.completed_by_tenant, r.tenant)
-                    completed.append(r.rid)
+            with TraceAnnotation("session.tokens"):
+                for lr, tok in zip(batch, toks, strict=True):
+                    r = lr.req
+                    tok = int(tok)
+                    lr.tokens.append(tok)
+                    r.n_generated += 1
+                    r.n_decoded += 1
+                    r.token_times.append(tend)
                     if tr is not None:
                         tr.emit(
-                            EventType.DONE, tend, rid=r.rid, tenant=r.tenant,
-                            pool=self.trace_label, slot=slot,
-                            n_generated=r.n_generated,
+                            EventType.TOKEN, tend, rid=r.rid, tenant=r.tenant,
+                            pool=self.trace_label, slot=lr.slot,
                         )
+                    self._emit(r, tok, tend)
+                    done = (
+                        tok == ecfg.eos_token
+                        or r.n_generated >= r.output_len
+                        or r.seq_len >= ecfg.max_len - 1
+                    )
+                    if done:
+                        r.phase = Phase.DONE
+                        r.done_time = tend
+                        slot = lr.slot
+                        srv.decode.release(lr)
+                        if self.prefix_cache is not None:
+                            self.prefix_cache.release(r.rid)  # idempotent unpin
+                        self.active.remove(lr)
+                        self.metrics.completed += 1
+                        self.metrics._bump(self.metrics.completed_by_tenant, r.tenant)
+                        completed.append(r.rid)
+                        if tr is not None:
+                            tr.emit(
+                                EventType.DONE, tend, rid=r.rid, tenant=r.tenant,
+                                pool=self.trace_label, slot=slot,
+                                n_generated=r.n_generated,
+                            )
 
         # when the only remaining work is KV on the wire, nudge the clock
         # toward the earliest transfer_ready_at so virtual-clock drivers
@@ -450,6 +484,13 @@ class ServeSession:
         if self.waiting_adm and not admitted and not self.queue and not self.active:
             nxt = min((lr.transfer_ready_at or 0.0) for lr in self.waiting_adm)
             clock.sleep(min(0.001, max(0.0, nxt - srv._now())))
+        if tr is not None:
+            # pool-level round record (rid = -1): host seconds from the
+            # round's `now` to its end, in both selects, and in the engines
+            tr.emit(
+                EventType.ROUND, now, pool=self.trace_label,
+                wall_s=srv.peek_now() - now, select_s=select_s, engine_s=engine_s,
+            )
         return completed
 
     # ----------------------------------------------------------------- run

@@ -303,6 +303,12 @@ class DisaggSimulator:
                 )
             r.phase = Phase.PREFILL
             offset = r.prefix_cached_tokens + r.prefilled_tokens
+            if tr is not None:
+                tr.emit(
+                    EventType.PREFILL_CHUNK, tp, rid=r.rid, tenant=r.tenant,
+                    pool=self.trace_label, start=offset, take=take,
+                    chunk_size=cfg.chunk_size,
+                )
             chunks.append((take, offset))
         step_t = cost.prefill_chunk_time(chunks)
         t_end = tp + step_t

@@ -1,5 +1,8 @@
 """Numerical invariants: MoE dispatch vs dense oracle, SSD chunk-size
 invariance, decode-vs-prefill consistency, blockwise attention exactness."""
+import itertools
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -121,3 +124,54 @@ def test_decode_step_consistent_with_prefill():
             params, jnp.asarray([[tok]], jnp.int32), jnp.asarray([t], jnp.int32), cache
         )
     np.testing.assert_allclose(np.asarray(lg), np.asarray(logits_pf), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("starts", [(0, 32, 64, 96), (0, 10, 42, 74, 106)])
+def test_chunked_prefill_writes_each_chunk_at_its_start(starts):
+    """Chunks of 32 over a 120-token prompt in a 128-row cache give the same
+    last-token logits as one pass over the whole prompt, wherever the chunks
+    start: a last chunk at 106 runs past the cache's end (106 + 32 > 128),
+    and its K/V must still land at rows 106-119."""
+    from repro.models.transformer import chunk_prefill_step
+
+    cfg = get_config("llama3-8b-smoke").replace(dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    max_len, chunk, n = 128, 32, 120
+    prompt = [int(t) for t in np.random.default_rng(1).integers(2, cfg.vocab_size, n)]
+    step = jax.jit(chunk_prefill_step, static_argnums=(4,))
+
+    def run(bounds, width):
+        cache = model.init_cache(1, max_len)
+        for a, b in itertools.pairwise(bounds):
+            toks = prompt[a:b] + [0] * (width - (b - a))
+            logits, cache = step(
+                params, jnp.asarray([toks], jnp.int32), jnp.asarray([a], jnp.int32),
+                jnp.asarray([b - a], jnp.int32), cfg, cache,
+            )
+        return np.asarray(logits)
+
+    ref = run((0, n), max_len)
+    got = run((*starts, n), chunk)
+    np.testing.assert_allclose(got, ref, rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("start", [0, 5, 96, 100, 120, 128])
+def test_chunk_write_lands_at_start_and_keeps_other_rows(start):
+    """Rows [start, start + rows) of the cache take the chunk's rows, up to
+    the cache's end; every other row is the cache's own. Where the chunk
+    fits, this is the plain ``dynamic_update_slice``, bit for bit."""
+    from repro.models.transformer import _write_chunk
+
+    m, rows = 128, 32
+    rng = np.random.default_rng(start)
+    cache = jnp.asarray(rng.standard_normal((m, 2, 4)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((rows, 2, 4)), jnp.float32)
+    got = np.asarray(jax.jit(partial(_write_chunk, rows=rows))(cache, new, jnp.int32(start)))
+    want = np.asarray(cache).copy()
+    n = min(rows, m - start)
+    want[start:start + n] = np.asarray(new)[:n]
+    np.testing.assert_array_equal(got, want)
+    if start + rows <= m:
+        plain = jax.lax.dynamic_update_slice(cache, new, (start, 0, 0))
+        np.testing.assert_array_equal(got, np.asarray(plain))

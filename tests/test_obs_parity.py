@@ -27,10 +27,16 @@ _BACKEND_TAGS = {EventType.ROUTE, EventType.DEFLECT}
 
 def _signature(events, with_times=True):
     """(per-rid lifecycle sequences, scheduler DECODE_STEP count), tags
-    excluded. ``with_times=False`` compares shape only (cross-time-base)."""
+    excluded. ``with_times=False`` compares shape only (cross-time-base).
+    With times, a live session's ROUND counters ride along under rid -1;
+    without, they are left out: the simulator has no host rounds."""
     per, steps = {}, 0
     for e in events:
         if e.type in _BACKEND_TAGS:
+            continue
+        if e.type is EventType.ROUND:
+            if with_times:
+                per.setdefault(-1, []).append((e.type.value, e.t))
             continue
         if e.rid < 0:
             steps += 1

@@ -48,8 +48,9 @@ def choose_size(
     max_len, report)``; raises ValueError when none fits.
 
     Each step is compiled from shapes and its `memory_analysis()` read
-    (arguments + outputs + temporaries: neither step donates its cache, so
-    old and new cache are both live). Beside the step sits what the engine
+    (arguments + outputs + temporaries, less the bytes an output aliases:
+    the decode step updates its donated cache in place, so the cache counts
+    once; the chunk step donates nothing). Beside the step sits what the engine
     keeps resident: the decode step runs while up to n-1 other requests
     hold a (1, max_len) prefill cache; a chunk step runs while the decode
     cache and n-1 other prefill caches are held. A device that reports no

@@ -148,6 +148,35 @@ def naive_attention(
     return out.reshape(b, sq, hq, d)
 
 
+def attention_with_new_row(
+    q: jax.Array,  # (B, 1, Hq, D)
+    ck: jax.Array,  # (B, M, Hkv, D) the lane's cached rows
+    cv: jax.Array,
+    k: jax.Array,  # (B, 1, Hkv, D) the new token's own K/V, not in the cache
+    v: jax.Array,
+    mask: jax.Array,  # (B, M) bool: cached rows the query sees
+    logit_cap: float = 0.0,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """One query per lane over its cached rows and its own new K/V row, one
+    softmax across both. The cache is only read, so the caller may write
+    the new row after the step instead of into a view before it."""
+    b, _, hq, d = q.shape
+    m, hkv = ck.shape[1], ck.shape[2]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    qg = q.reshape(b, hkv, hq // hkv, d)
+    cached = jnp.einsum("bhgd,bkhd->bhgk", qg, ck, preferred_element_type=jnp.float32)
+    own = jnp.einsum("bhgd,bhd->bhg", qg, k[:, 0], preferred_element_type=jnp.float32)
+    scores = softcap(jnp.concatenate([cached, own[..., None]], -1) * scale, logit_cap)
+    seen = jnp.concatenate([mask, jnp.ones((b, 1), bool)], -1)
+    probs = jax.nn.softmax(jnp.where(seen[:, None, None, :], scores, _NEG_INF), axis=-1)
+    out = jnp.einsum(
+        "bhgk,bkhd->bhgd", probs[..., :m].astype(cv.dtype), cv,
+        preferred_element_type=jnp.float32,
+    ) + probs[..., m:] * v[:, 0, :, None, :].astype(jnp.float32)
+    return out.astype(v.dtype).reshape(b, 1, hq, d)
+
+
 def blockwise_attention(
     q: jax.Array,  # (B, Sq, Hq, D)
     k: jax.Array,  # (B, Skv, Hkv, D)

@@ -1,17 +1,21 @@
 """Decoder trunk: dense / MoE / SSM / hybrid families, scan-over-layers.
 
-Three entry points (all shape-polymorphic over batch):
+Entry points (all shape-polymorphic over batch):
   forward_train(params, inputs, cfg)                -> logits (B, S, V)
   prefill_step(params, inputs, cfg, valid_len)      -> (last_logits (B,V), kv_out)
   decode_step(params, tokens, positions, cfg, cache)-> (logits (B,V), cache')
+  decode_rows(params, tokens, positions, cfg, cache, view)
+                                                    -> (logits (B,V), new K/V rows)
 
 Prefill produces the KV pytree that a disaggregated deployment ships to the
-decode instance; decode consumes/updates a preallocated cache.
+decode instance; decode consumes/updates a preallocated cache. The serving
+engine decodes with `decode_rows`, which only reads the cache and leaves the
+writing of each lane's new row to the engine (serving/kvcache.py).
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +25,8 @@ from repro.dist.act_sharding import constrain_batch
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (
     attention,
+    attention_mask,
+    attention_with_new_row,
     dense_init,
     dtype_of,
     embed_init,
@@ -475,6 +481,57 @@ def decode_step(
 
     logits = logits_from_hidden(params, x[:, 0], cfg)
     return logits, new_cache
+
+
+def decode_rows(
+    params: Dict,
+    tokens: jax.Array,  # (B, 1) int32
+    positions: jax.Array,  # (B,) each lane's position: its new row goes there
+    cfg: ModelConfig,
+    cache: Dict,  # plain {"k", "v"}, leaves (L, ...)
+    view: Callable[[jax.Array], jax.Array],
+):
+    """Single-token decode that only reads the cache: the serving engine's
+    path, which writes each lane's new K/V row itself, in place.
+
+    Each layer's attention reads ``view(leaf[l])`` -> (B, M, Hkv, Dh), the
+    lanes' cached rows, at positions below each lane's own, plus the new
+    token's K/V, which is not in the cache. No cache view is rewritten, so
+    unlike `decode_step` there is no per-layer select for SPMD to
+    partition; this path is for an unsharded cache. Returns
+    (logits (B, V), rows) with rows ``dict(k, v)`` of (L, B, Hkv, Dh).
+    """
+    assert cfg.family in ("dense", "vlm", "moe") and set(cache) == {"k", "v"}
+    x = constrain_batch(params["embed"][tokens])
+    b = tokens.shape[0]
+    pos2 = positions[:, None]
+
+    def body(h, xs):
+        layer, is_local, ck, cv = xs
+        win = _layer_window(cfg, is_local)
+        q, k, v = _attn_qkv(layer["attn"], rms_norm(h, layer["pre_attn_norm"], cfg.norm_eps), pos2, cfg)
+        ck, cv = view(ck), view(cv)
+        if cfg.attn_impl == "pallas":
+            # the kernel reads one materialized view: the row goes into it
+            lane = jnp.arange(b)
+            ck = ck.at[lane, positions].set(k[:, 0])
+            cv = cv.at[lane, positions].set(v[:, 0])
+            out = attention(
+                q, ck, cv, pos2, positions + 1, window=win,
+                logit_cap=cfg.attn_logit_softcap, impl="pallas",
+            )
+        else:
+            seen = attention_mask(pos2, ck.shape[1], positions, win)[:, 0]
+            out = attention_with_new_row(q, ck, cv, k, v, seen, cfg.attn_logit_softcap)
+        out = out.reshape(b, 1, cfg.num_heads * cfg.resolved_head_dim)
+        h = h + jnp.einsum("bse,ed->bsd", out, layer["attn"]["wo"])
+        h = h + _ffn(layer, h, cfg)
+        return h, (k[:, 0], v[:, 0])
+
+    x, (k, v) = jax.lax.scan(
+        body, x, (params["layers"], _layer_flags(cfg, cfg.num_layers), cache["k"], cache["v"])
+    )
+    return logits_from_hidden(params, x[:, 0], cfg), dict(k=k, v=v)
 
 
 def chunk_prefill_step(

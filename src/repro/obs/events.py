@@ -21,8 +21,11 @@ The taxonomy is the request lifecycle every backend shares:
     DECODE_STEP     one engine decode step (rid = -1: a pool-level event;
                     ``data``: batch, step_time, active, tpot_budget; from a
                     live engine also ``bucket`` (lanes of the padded batch),
-                    ``positions`` (each live lane's position) and the
-                    host's ``launch_s`` / ``sync_s`` of the step)
+                    ``positions`` (each live lane's position), the
+                    host's ``launch_s`` / ``sync_s`` of the step, and
+                    ``kv_write``: "row" where the step wrote each live
+                    lane's new K/V row in place, "slot" where it gathered
+                    and scattered whole slots)
     TOKEN           one token produced for a request
     CANCEL          client withdrew the request (``data["stage"]`` says
                     where it was caught); terminal, Phase.CANCELLED

@@ -697,7 +697,7 @@ class DisaggSession:
                     active=len(w.active),
                     tpot_budget=min(l.req.slo.tpot for l in batch),
                     bucket=st.bucket, positions=st.positions,
-                    launch_s=st.launch_s, sync_s=st.sync_s,
+                    launch_s=st.launch_s, sync_s=st.sync_s, kv_write=st.kv_write,
                 )
             with TraceAnnotation("session.tokens"):
                 for lr, tok in zip(batch, toks, strict=True):

@@ -2,9 +2,11 @@
 
 PrefillEngine owns a prefill cache per in-flight request and executes
 chunked prefill steps chosen by the prefill scheduler (urgency/FCFS/...).
-DecodeEngine owns the slot cache; each step the decode scheduler
-(slack-guided / continuous) picks the sub-batch, which is gathered into a
-power-of-two bucket, decoded, and scattered back. Observed wall-clock step
+DecodeEngine owns the slot cache (or page pool) and updates it in place:
+each step the decode scheduler (slack-guided / continuous) picks the
+sub-batch, padded to a power-of-two bucket, and the jitted step, which is
+handed the cache to consume, writes each lane's new K/V row into it.
+Observed wall-clock step
 times feed the LUT and the prefill-throughput estimator online — the same
 adaptation loop the paper runs on GPUs.
 
@@ -36,16 +38,20 @@ if TYPE_CHECKING:  # import for annotation only: engine stays obs-free
     from repro.obs.events import TraceRecorder
 from repro.configs.base import ModelConfig
 from repro.models.model import Model, cache_struct
-from repro.models.transformer import chunk_prefill_step, decode_step
+from repro.models.transformer import chunk_prefill_step, decode_rows, decode_step
 from repro.policies import PolicySpec, make_decode, make_prefill
 from repro.serving.clock import Clock, MonotonicClock
 from repro.serving.kvcache import (
     PageAllocator,
     SlotAllocator,
-    gather_pages,
+    cache_batch_dim,
     gather_slots,
-    scatter_pages,
+    page_view,
     scatter_slots,
+    slot_view,
+    write_page_rows,
+    write_slot_rows,
+    writes_rows,
 )
 from repro.serving.prefixcache import PrefixCache
 from repro.serving.sampler import sample
@@ -64,27 +70,57 @@ def _bucket(n: int, buckets: Sequence[int]) -> int:
 _chunk_step = jax.jit(chunk_prefill_step, static_argnums=(4,))
 
 
-# The named scopes label the device ops of each part of a decode step in a
-# profiler trace (the ops' ``tf_op`` / ``long_name``), so a trace says which
-# copies are the KV gather and scatter and which ops are the model.
-@partial(jax.jit, static_argnums=(5,))
+# The decode steps and the attach writes consume the cache they are given
+# (``donate_argnums``), so XLA updates it in place: the engine keeps only the
+# array each call returns (DESIGN.md §kvcache). The named scopes label the
+# device ops of each part of a decode step in a profiler trace (the ops'
+# ``tf_op`` / ``long_name``): ``kv_gather`` the reads of the lanes' rows
+# (inside ``model`` on the row path), ``model`` the trunk, ``kv_scatter``
+# the writes into the cache.
+@partial(jax.jit, static_argnums=(5,), donate_argnums=(3,))
 def _slot_step(params, tokens, positions, cache, slot_idx, cfg: ModelConfig):
-    with jax.named_scope("kv_gather"):
-        sub = gather_slots(cfg, cache, slot_idx)
+    if not writes_rows(cache):  # windowed ring, ssm, hybrid: whole slots
+        with jax.named_scope("kv_gather"):
+            sub = gather_slots(cfg, cache, slot_idx)
+        with jax.named_scope("model"):
+            logits, sub = decode_step(params, tokens, positions, cfg, sub)
+        with jax.named_scope("kv_scatter"):
+            return logits, scatter_slots(cfg, cache, sub, slot_idx)
     with jax.named_scope("model"):
-        logits, sub2 = decode_step(params, tokens, positions, cfg, sub)
+        logits, rows = decode_rows(params, tokens, positions, cfg, cache, slot_view(slot_idx))
     with jax.named_scope("kv_scatter"):
-        return logits, scatter_slots(cfg, cache, sub2, slot_idx)
+        return logits, write_slot_rows(cache, rows, slot_idx, positions)
 
 
-@partial(jax.jit, static_argnums=(5,))
+@partial(jax.jit, static_argnums=(5,), donate_argnums=(3,))
 def _page_step(params, tokens, positions, pool, page_idx, cfg: ModelConfig):
-    with jax.named_scope("kv_gather"):
-        sub = gather_pages(cfg, pool, page_idx)
     with jax.named_scope("model"):
-        logits, sub2 = decode_step(params, tokens, positions, cfg, sub)
+        logits, rows = decode_rows(params, tokens, positions, cfg, pool, page_view(page_idx))
     with jax.named_scope("kv_scatter"):
-        return logits, scatter_pages(cfg, pool, sub2, page_idx)
+        return logits, write_page_rows(pool, rows, page_idx, positions)
+
+
+@partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))
+def _attach_slot(cache, kv, slot, cfg: ModelConfig):
+    """Write a request's (1, max_len) prefill cache into its slot."""
+    out = {}
+    for name, leaf in cache.items():
+        at = [0] * leaf.ndim
+        at[cache_batch_dim(cfg, name)] = slot
+        out[name] = jax.lax.dynamic_update_slice(leaf, kv[name], at)
+    return out
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _attach_pages(pool, kv, dest):
+    """Write a request's (1, max_len) prefill cache into the pool, block j
+    into page ``dest[j]``; blocks that land nowhere go to the scratch page."""
+    out = {}
+    for name, leaf in pool.items():
+        src = kv[name]  # (L, 1, max_len, ...)
+        blocks = src.reshape(src.shape[0], dest.shape[0], leaf.shape[2], *src.shape[3:])
+        out[name] = leaf.at[:, dest].set(blocks)
+    return out
 
 
 def _to(tree: Any, device) -> Any:
@@ -156,14 +192,18 @@ class LiveRequest:
 @dataclass
 class DecodeStepStats:
     """What one `DecodeEngine.step` did: lanes of the padded batch, each
-    live lane's position, and the host's virtual seconds from entry until
+    live lane's position, the host's virtual seconds from entry until
     the step program and the sampler were dispatched (``launch_s``) and
-    then blocked until the tokens were on the host (``sync_s``)."""
+    then blocked until the tokens were on the host (``sync_s``), and how
+    the step wrote the cache (``kv_write``): ``"row"``, each live lane's
+    new K/V row in place, or ``"slot"``, the lanes' whole slots gathered
+    and scattered back (windowed, ssm and hybrid caches)."""
 
     bucket: int
     positions: List[int]
     launch_s: float
     sync_s: float
+    kv_write: str
 
 
 class PrefillEngine:
@@ -251,6 +291,7 @@ class DecodeEngine:
             # (the paged scratch page is the same idea at page granularity)
             self.cache = model.init_cache(ecfg.max_slots + 1, ecfg.max_len, device)
             self.scratch_slot = ecfg.max_slots
+        self.kv_write = "row" if writes_rows(self.cache or self.pool) else "slot"
 
     def _init_paged(self, cfg) -> None:
         ecfg = self.ecfg
@@ -326,29 +367,20 @@ class DecodeEngine:
         # the prefill cache lives on the prefill server's device: bring it
         # here first (a no-op when both servers share a device)
         kv = _to(lr.prefill_cache, self.device)
+        lr.prefill_cache = None
         if self.pages is None:
-            self.cache = scatter_slots(
-                self.model.cfg, self.cache, kv, jnp.asarray([lr.slot], jnp.int32)
-            )
-            lr.prefill_cache = None
+            self.cache = _attach_slot(self.cache, kv, np.int32(lr.slot), self.model.cfg)
             return
         r = lr.req
-        ps = self.page_size
         table = lr.page_table
         n_shared = len(lr.shared_pages or ())  # already live in this pool?
         if lr.kv_src is not self:
             n_shared = 0  # head bytes were seeded from another engine's pool
-        if len(table) > n_shared:
-            fresh = jnp.asarray(table[n_shared:], jnp.int32)
-            for name, leaf in self.pool.items():
-                src = kv[name]  # (L, 1, max_len, ...)
-                blocks = src.reshape(
-                    src.shape[0], self.ecfg.max_len // ps, ps, *src.shape[3:]
-                )
-                self.pool[name] = leaf.at[:, fresh].set(
-                    blocks[:, n_shared : len(table)]
-                )
-        lr.prefill_cache = None
+        # one shape for every request: shared and unused blocks go to scratch
+        sp = self.scratch_page
+        dest = [sp] * n_shared + table[n_shared:]
+        dest += [sp] * (self.pages_per_req - len(dest))
+        self.pool = _attach_pages(self.pool, kv, np.asarray(dest, np.int32))
         # index the landed prompt in the radix cache: later prompts sharing
         # this head link these pages instead of recomputing the KV
         self.prefix.assign_pages(lr.tokens[: r.input_len], table)
@@ -396,13 +428,15 @@ class DecodeEngine:
                 out = np.asarray(toks_out)[:n]
             t2 = self._peek()
         scale = ecfg.time_scale
-        self.last_step = DecodeStepStats(bs, pos[:n], (t1 - t0) * scale, (t2 - t1) * scale)
+        self.last_step = DecodeStepStats(
+            bs, pos[:n], (t1 - t0) * scale, (t2 - t1) * scale, self.kv_write
+        )
         return out
 
     def _run(self, toks: List[int], pos: List[int], lanes: List, bs: int) -> jax.Array:
         """Run the jitted step on `lanes` padded to `bs` with scratch lanes
-        (pad lanes write scratch only); updates the cache/pool in place of
-        the old one and returns the logits."""
+        (pad lanes write scratch only); the step consumes the cache/pool
+        and the engine keeps the one it returns. Returns the logits."""
         cfg = self.model.cfg
         tokens = jnp.asarray(toks, jnp.int32)[:, None]
         positions = jnp.asarray(pos, jnp.int32)
@@ -422,12 +456,22 @@ class DecodeEngine:
 
     def warmup(self) -> None:
         """Compile the decode step at every batch bucket a sub-batch of up
-        to ``max_slots`` requests can land in. All lanes are scratch, so no
-        live request's KV is touched."""
+        to ``max_slots`` requests can land in, and the attach write. All
+        lanes and the attached prefill cache go to scratch, so no live
+        request's KV is touched."""
         ecfg = self.ecfg
         sizes = sorted({_bucket(n, ecfg.decode_buckets) for n in range(1, ecfg.max_slots + 1)})
         for bs in sizes:
             self._run([0] * bs, [0] * bs, [], bs).block_until_ready()
+        kv = self.model.init_cache(1, ecfg.max_len, self.device)
+        if self.pages is None:
+            self.cache = _attach_slot(
+                self.cache, kv, np.int32(self.scratch_slot), self.model.cfg
+            )
+        else:
+            dest = np.full((self.pages_per_req,), self.scratch_page, np.int32)
+            self.pool = _attach_pages(self.pool, kv, dest)
+        jax.block_until_ready(self.cache or self.pool)
 
 
 class DisaggServer:

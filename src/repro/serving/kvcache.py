@@ -2,8 +2,15 @@
 
 Host-side allocators track which slots/pages are live and enforce the
 admission cap (the paper's memory-bound decode regime); device-side helpers
-gather/scatter per-request cache slices so a scheduler-chosen sub-batch can
-be decoded without touching delayed requests.
+read a scheduler-chosen sub-batch's cache rows and write each lane's new
+row back, so the sub-batch is decoded without touching delayed requests.
+
+A plain ``{"k", "v"}`` attention cache is updated in place: the decode step
+reads the lanes' rows through a view (`slot_view` / `page_view`) and writes
+only each live lane's new row (`write_slot_rows` / `write_page_rows`).
+Any other cache (windowed ring, ssm, hybrid) is gathered whole by slot
+(`gather_slots`), decoded and scattered back (`scatter_slots`);
+`writes_rows` tells the two apart.
 
 Two allocation substrates coexist:
 
@@ -13,8 +20,8 @@ Two allocation substrates coexist:
   * `PageAllocator` — fixed-size pages with per-request page tables and
     refcounted sharing (vLLM/sglang's paged-KV pattern). Matched prefix
     blocks map to *live* pages, so shared prompt heads are neither recomputed
-    nor double-stored; `gather_pages`/`scatter_pages` are the page-table
-    twins of `gather_slots`/`scatter_slots`.
+    nor double-stored; `page_view`/`write_page_rows` are the page-table
+    twins of `slot_view`/`write_slot_rows`.
 
 See DESIGN.md §kvcache.
 """
@@ -51,6 +58,41 @@ def scatter_slots(cfg: ModelConfig, cache: Dict, sub: Dict, slot_idx: jax.Array)
         idx = [slice(None)] * leaf.ndim
         idx[ax] = slot_idx
         out[name] = leaf.at[tuple(idx)].set(sub[name])
+    return out
+
+
+def writes_rows(cache: Dict) -> bool:
+    """Whether a decode step over `cache` writes only each lane's new row
+    (a plain k/v attention cache) rather than whole slots."""
+    return set(cache) == {"k", "v"}
+
+
+def slot_view(slot_idx: jax.Array) -> Callable[[jax.Array], jax.Array]:
+    """Read one layer's rows (slots, max_len, ...) of the lanes' slots:
+    (B, max_len, ...). Its ops carry the ``kv_gather`` scope in a profile."""
+
+    def read(leaf: jax.Array) -> jax.Array:
+        with jax.named_scope("kv_gather"):
+            return jnp.take(leaf, slot_idx, axis=0)
+
+    return read
+
+
+def write_slot_rows(cache: Dict, rows: Dict, slot_idx: jax.Array, positions: jax.Array) -> Dict:
+    """Write each lane's new row ``rows[name][:, i]`` (L, B, ...) at
+    ``(slot_idx[i], positions[i])`` of the (L, slots, max_len, ...) leaves,
+    one single-row update per lane, in place on a donated cache. A batched
+    scatter here makes the TPU compiler relayout the whole cache where the
+    minor dim is 64 wide (minicpm-2b's head_dim; DESIGN.md §kvcache)."""
+    out = {}
+    for name, leaf in cache.items():
+        r = rows[name]
+        zeros = (0,) * (leaf.ndim - 3)
+        for i in range(r.shape[1]):
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, r[:, i, None, None], (0, slot_idx[i], positions[i], *zeros)
+            )
+        out[name] = leaf
     return out
 
 
@@ -116,48 +158,32 @@ class SlotAllocator:
             self.free = [s for s in range(self.max_slots) if s not in live][::-1]
 
 
-def gather_pages(cfg: ModelConfig, pool: Dict, page_idx: jax.Array) -> Dict:
-    """Assemble per-request contiguous cache views from a page pool.
-
-    ``pool`` leaves are ``(L, n_pages, page_size, ...)``; ``page_idx`` is the
-    ``(batch, pages_per_req)`` page table (pad rows/tails use the scratch
-    page). Returns leaves shaped ``(L, batch, pages_per_req * page_size,
-    ...)`` — exactly what `gather_slots` hands the model, so `decode_step`
-    runs unchanged on top. Positions beyond a request's valid length land in
-    scratch/garbage pages, which the attention mask zeroes out exactly
-    (`kv_pos < kv_valid`), keeping paged logits bit-identical to slot-mode.
-    """
+def page_view(page_idx: jax.Array) -> Callable[[jax.Array], jax.Array]:
+    """Read one layer's pages (n_pages, page_size, ...) through the
+    ``(B, pages_per_req)`` page table (pad rows and tails on the scratch
+    page): (B, pages_per_req * page_size, ...), the same view `slot_view`
+    gives. Rows past a request's valid length are scratch or stale, and the
+    attention mask leaves them out exactly, so paged logits equal slot-mode
+    logits bit for bit."""
     b, p = page_idx.shape
     flat = page_idx.reshape(-1)
-    out = {}
-    for name, leaf in pool.items():
-        if cache_batch_dim(cfg, name) != 1:
-            raise ValueError(
-                f"paged KV supports attention-style (L, B, T, ...) cache "
-                f"leaves only; leaf {name!r} has its batch on another axis"
-            )
-        g = jnp.take(leaf, flat, axis=1)
-        out[name] = g.reshape(leaf.shape[0], b, p * leaf.shape[2], *leaf.shape[3:])
-    return out
+
+    def read(leaf: jax.Array) -> jax.Array:
+        with jax.named_scope("kv_gather"):
+            g = jnp.take(leaf, flat, axis=0)
+            return g.reshape(b, p * leaf.shape[1], *leaf.shape[2:])
+
+    return read
 
 
-def scatter_pages(cfg: ModelConfig, pool: Dict, sub: Dict, page_idx: jax.Array) -> Dict:
-    """Inverse of `gather_pages`: write per-request views back to the pool.
-
-    Shared pages appear in several rows of ``page_idx``; decode only ever
-    writes at a request's *own* position (>= its private region), so every
-    duplicate index carries the page's unchanged bytes and the duplicate
-    ``.at[].set`` is value-deterministic. Scratch-page duplicates hold
-    garbage that nothing reads back unmasked.
-    """
-    b, p = page_idx.shape
-    flat = page_idx.reshape(-1)
-    out = {}
-    for name, leaf in pool.items():
-        ps = leaf.shape[2]
-        s = sub[name].reshape(leaf.shape[0], b * p, ps, *leaf.shape[3:])
-        out[name] = leaf.at[:, flat].set(s)
-    return out
+def write_page_rows(pool: Dict, rows: Dict, page_idx: jax.Array, positions: jax.Array) -> Dict:
+    """`write_slot_rows` through the page table: lane i's row lands at
+    offset ``positions[i] % page_size`` of page
+    ``page_idx[i, positions[i] // page_size]``. Decode writes only at a
+    request's own position, past any shared prefix page."""
+    ps = pool["k"].shape[2]
+    page = jnp.take_along_axis(page_idx, (positions // ps)[:, None], axis=1)[:, 0]
+    return write_slot_rows(pool, rows, page, positions % ps)
 
 
 @dataclass

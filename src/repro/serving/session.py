@@ -431,7 +431,8 @@ class ServeSession:
                 # scheduler packed, the engine step time, and the tightest
                 # TPOT budget in the batch — obs/slo.py's budget series —
                 # then what the engine did: the padded bucket, each lane's
-                # position, and the host's launch and sync seconds
+                # position, the host's launch and sync seconds, and how the
+                # step wrote the cache
                 st = srv.decode.last_step
                 tr.emit(
                     EventType.DECODE_STEP, tend, pool=self.trace_label,
@@ -439,7 +440,7 @@ class ServeSession:
                     active=len(self.active),
                     tpot_budget=min(l.req.slo.tpot for l in batch),
                     bucket=st.bucket, positions=st.positions,
-                    launch_s=st.launch_s, sync_s=st.sync_s,
+                    launch_s=st.launch_s, sync_s=st.sync_s, kv_write=st.kv_write,
                 )
             with TraceAnnotation("session.tokens"):
                 for lr, tok in zip(batch, toks, strict=True):

@@ -187,3 +187,47 @@ def test_disagg_1p1d_never_deflection_events_match_router(tiny_model):
     # the two timelines differ only in backend tags and pool labels
     pools_d = {e.pool for e in tr_d.events}
     assert {"prefill:0", "decode:0"} <= pools_d
+
+
+def test_decode_steps_say_how_they_wrote_the_cache(tiny_model):
+    """Every DECODE_STEP names the engine's cache write: "row", each live
+    lane's new row in place, for the dense fixture's plain k/v cache.
+    Tracing changes no token and no timing."""
+    from repro.serving.session import ServeSession
+
+    cfg = tiny_model[0]
+    runs = []
+    for tr in (TraceRecorder(), None):
+        pairs = _requests(cfg)
+        sess = ServeSession(_server(tiny_model), trace=tr)
+        sess.run(pairs)
+        runs.append((sess.outputs, [(r.rid, r.ttft(), r.mean_tpot()) for r, _ in pairs]))
+        if tr is not None:
+            steps = [e for e in tr.events if e.type is EventType.DECODE_STEP]
+            assert steps and all(e.data["kv_write"] == "row" for e in steps)
+    assert runs[0] == runs[1]
+
+
+def test_windowed_engine_steps_say_slot():
+    """The windowed ring cache (max_len 64 > window 32) is decoded by whole
+    slots, and each step says so in the stats DECODE_STEP carries. A session
+    cannot serve it (chunked prefill takes a plain k/v cache), so the
+    engine is driven directly: attach a prefill cache, then step."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving.engine import DecodeEngine, EngineConfig, LiveRequest
+
+    cfg = get_config("gemma2-9b-smoke").replace(dtype="float32")
+    model = build_model(cfg)
+    eng = DecodeEngine(model, model.init(jax.random.key(0)),
+                       EngineConfig(max_slots=4, max_len=64), None, peek=lambda: 0.0)
+    req = Request(rid=0, arrival=0.0, input_len=3, output_len=2,
+                  slo=SLOSpec(ttft=120.0, tpot=10.0))
+    req.prefilled_tokens = 3
+    lr = LiveRequest(req=req, tokens=[5, 6, 7], prefill_cache=model.init_cache(1, 64))
+    assert eng.admit(lr)
+    for _ in range(2):
+        eng.step([lr], jax.random.key(1))
+        assert eng.last_step.kv_write == "slot"

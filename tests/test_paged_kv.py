@@ -2,7 +2,7 @@
 
   * `PageAllocator` bookkeeping: tables, refcounted sharing, O(1) token
     accounting, the pressure-evictor hook, shortage-leaves-state-untouched
-  * `gather_pages`/`scatter_pages` round-trip through a real model pool
+  * `write_page_rows`/`page_view` round-trip through a real model pool
   * pin semantics (the PR-5 eviction bug): LRU eviction never drops blocks
     an in-flight request admitted against, nor pages a live table still maps
   * the parity contract: a paged engine is bit-identical to the slot engine
@@ -27,7 +27,7 @@ from repro.policies import make_prefill
 from repro.serving.clock import ManualClock
 from repro.serving.disagg import DisaggSession
 from repro.serving.engine import DisaggServer, EngineConfig
-from repro.serving.kvcache import PageAllocator, gather_pages, scatter_pages
+from repro.serving.kvcache import PageAllocator, page_view, write_page_rows
 from repro.serving.prefixcache import PrefixCache
 from repro.serving.session import ServeSession
 
@@ -133,22 +133,29 @@ class TestPageAllocator:
 
 
 def test_gather_scatter_pages_roundtrip(tiny_model):
+    """Rows written through the page table read back through the page view
+    at each lane's position, and nothing else in the pool changes."""
     cfg, model, _ = tiny_model
     ps, n_pages = 4, 8
     pool = model.init_cache(n_pages, ps)
     table = jnp.array([[3, 1, 5], [0, 6, 2]])  # two requests, three pages each
+    positions = jnp.array([6, 11])  # page 1 offset 2 of its table, page 2 offset 3
     rng = np.random.default_rng(1)
-    sub = {
+    rows = {
         name: jnp.asarray(
-            rng.standard_normal((leaf.shape[0], 2, 3 * ps, *leaf.shape[3:])),
-            dtype=leaf.dtype,
+            rng.standard_normal((leaf.shape[0], 2, *leaf.shape[3:])), dtype=leaf.dtype
         )
         for name, leaf in pool.items()
     }
-    pool2 = scatter_pages(cfg, pool, sub, table)
-    back = gather_pages(cfg, pool2, table)
+    before = {name: np.asarray(leaf) for name, leaf in pool.items()}
+    pool2 = write_page_rows(pool, rows, table, positions)
+    view = page_view(table)
     for name in pool:
-        np.testing.assert_array_equal(np.asarray(back[name]), np.asarray(sub[name]))
+        back = np.stack([np.asarray(view(layer)) for layer in pool2[name]])
+        for lane, p in enumerate([6, 11]):
+            np.testing.assert_array_equal(back[:, lane, p], np.asarray(rows[name][:, lane]))
+        changed = np.argwhere(np.any(np.asarray(pool2[name]) != before[name], axis=(0, 3, 4)))
+        assert sorted(map(tuple, changed)) == [(1, 2), (2, 3)]
 
 
 # ---------------------------------------------------- pin/eviction regression

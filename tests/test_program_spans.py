@@ -86,6 +86,7 @@ def test_round_counters(tiny_model, kind):
         assert len(d["positions"]) == d["batch"]
         assert all(4 <= p < 64 for p in d["positions"])
         assert d["launch_s"] >= 0 and d["sync_s"] >= 0
+        assert d["kv_write"] == "row"  # a plain k/v cache: rows in place
     rounds = by(EventType.ROUND)
     assert rounds and all(e.rid == -1 for e in rounds)
     for e in rounds:

@@ -8,6 +8,7 @@ module is imported), so every test worker collects the same tests and only
 the worker given this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.kernels.decode_attention import ops as decode_ops
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.prefill_attention import ops as prefill_ops
@@ -84,9 +86,18 @@ def test_decode_kernel_compiles(one_chip, arch, b, s):
     assert "tpu_custom_call" in text
 
 
+def _footprint(m) -> int:
+    """Device bytes of one compiled step: the cache it aliases counts once."""
+    return (
+        m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+        - m.alias_size_in_bytes
+    )
+
+
 def test_engine_steps_compile_at_full_width(topo):
     """minicpm-2b's decode and chunk-prefill steps at the smoke's size fit
-    one v5e; eight slots (the next size up) do not."""
+    one v5e, and so does the decode step at eight slots (the next size up)
+    now that it updates its cache in place instead of holding two."""
     cfg = get_config("minicpm-2b")
     model = build_model(cfg)
     mem = {
@@ -97,10 +108,45 @@ def test_engine_steps_compile_at_full_width(topo):
     cache = tree_bytes(cache_struct(cfg, SMOKE_SIZE["max_slots"] + 1, SMOKE_SIZE["max_len"]))
     assert mem["decode"].argument_size_in_bytes >= params + cache
     for m in mem.values():
-        assert m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes < V5E_HBM
+        assert _footprint(m) < V5E_HBM
     wide = lower_steps(model, EngineConfig(**dict(SMOKE_SIZE, max_slots=8)), topo.devices[0])
-    m = wide["decode"].compile().memory_analysis()
-    assert m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes > V5E_HBM
+    assert _footprint(wide["decode"].compile().memory_analysis()) < V5E_HBM
+
+
+# The benchmark's two decode cells, built here (tier-1 imports nothing of
+# benchmarks/): minicpm-2b at 4 x 1024, and Mistral-7B-v0.3's widths cut to
+# 16 layers at 8 x 2048. Their caches' minor dims (36 x 64 and 8 x 128)
+# tile differently on the chip, and a batched scatter of the new rows, or
+# rows written into a cache carried through the layer scan, relayouts the
+# whole 64-wide cache.
+DECODE_CELLS = {
+    "minicpm-2b": (get_config("minicpm-2b"), dict(max_slots=4, max_len=1024)),
+    "mistral-7b-v0.3-16l": (
+        ModelConfig(
+            name="mistral-7b-v0.3-16l", family="dense", num_layers=16, d_model=4096,
+            num_heads=32, num_kv_heads=8, head_dim=128, d_ff=14336, vocab_size=32768,
+            rope_theta=1e6, norm_eps=1e-5, tie_embeddings=False,
+        ),
+        dict(max_slots=8, max_len=2048),
+    ),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
+def test_decode_step_updates_the_cache_in_place(topo, cell):
+    """The compiled decode step aliases its whole cache to its output and
+    copies no cache-sized array: the new rows are written in place."""
+    cfg, size = DECODE_CELLS[cell]
+    low = lower_steps(build_model(cfg), EngineConfig(chunk_size=256, **size), topo.devices[0])
+    compiled = low["decode"].compile()
+    cache = cache_struct(cfg, size["max_slots"] + 1, size["max_len"])
+    assert compiled.memory_analysis().alias_size_in_bytes >= tree_bytes(cache)
+    shapes = {",".join(map(str, x.shape)) for x in cache.values()}
+    copies = [
+        line for line in compiled.as_text().splitlines()
+        if re.search(r"= \w+\[(" + "|".join(shapes) + r")\]\{[^}]*\} copy\(", line)
+    ]
+    assert not copies, copies[:2]
 
 
 def test_engine_steps_compile_with_pallas_kernels(topo, monkeypatch):

@@ -45,14 +45,13 @@ def device():
 
 
 def cmd_size(args) -> None:
-    import modelspec
+    import harness
     from repro.launch.sizing import choose_size, describe
     from repro.models import build_model
 
-    conf = modelspec.load(HERE / "configs" / f"{args.config}.json")
-    spec = modelspec.spec_of(conf)
+    _, arch, spec = harness.load_config(HERE, args.config)
     sizes = [tuple(int(x) for x in s.split("x")) for s in args.sizes]
-    _, _, rep = choose_size(build_model(modelspec.model_config(spec)), args.requests,
+    _, _, rep = choose_size(build_model(arch.model_config(spec)), args.requests,
                             args.seq_len, device(), sizes=sizes, chunk=args.chunk)
     log(json.dumps(dict(config=args.config, candidates=sizes, n_requests=args.requests,
                         seq_len=args.seq_len, report=rep)))
@@ -73,14 +72,13 @@ def cmd_slo(args) -> None:
     import numpy as np
 
     import harness
-    import modelspec
     from repro.core.request import Request, SLOSpec
     from repro.serving.session import ServeSession
 
-    conf = modelspec.load(HERE / "configs" / f"{args.config}.json")
-    c = harness.Cell("slo", conf, {}, {}, modelspec.spec_of(conf))
+    conf, arch, spec = harness.load_config(HERE, args.config)
+    c = harness.Cell("slo", conf, {}, {}, arch, spec)
     dev = device()
-    srv = harness.build_server(c, 0, dev, False, None)
+    srv = harness.build_server(c, 0, dev)
     harness.warm_up(srv, c, dev)
     rng = np.random.default_rng(0)
 
@@ -119,13 +117,13 @@ def cmd_sweep(args) -> None:
 
     c = _cell_with(args.cell, args.slo_file)
     dev = device()
-    srv = harness.build_server(c, args.seed, dev, False, None)
+    srv = harness.build_server(c, args.seed, dev)
     bar = harness.warm_up(srv, c, dev)
     k0 = c.conf["slo"]["k"]
     for rate in args.rates:
         c.cell["rate"] = rate
         try:
-            d = harness.drive(srv, c, args.seed, args.seconds, False, None, bar,
+            d = harness.drive(srv, c, args.seed, args.seconds, False, bar,
                               harness.CompileCounter())
         except Exception as e:  # an overloaded engine can run out of memory
             log(json.dumps(dict(cell=args.cell, rate=rate, error=repr(e)[:300])))

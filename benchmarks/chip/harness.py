@@ -4,7 +4,9 @@ A cell is found by name: ``cells/<name>.json`` gives its configuration,
 traffic mix, rate, drain limit and the check's limit; the configuration is
 ``configs/<config>.json`` and the mix ``traffic/<traffic>.json``. Per-layer
 metrics are readers in ``layer_metrics/<metric>.py``, each with a
-``read(run)`` that returns a number or None. Nothing here names a cell.
+``read(run)`` that returns a number or None. The model is reached only
+through the architecture module the configuration names (``modelspec``).
+Nothing here names a cell, a configuration or an architecture.
 
 `run_cell` is the whole run apart from finding the chip:
 
@@ -24,7 +26,6 @@ from __future__ import annotations
 
 import collections
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -32,6 +33,7 @@ import tempfile
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -45,17 +47,17 @@ import traffic
 import weights
 import work
 import xplane
-from spans import Spans
 
 HERE = Path(__file__).resolve().parent
 
 # every served token is the argmax; no token is an end-of-sequence, so each
 # request runs to its full length
 NO_EOS = -1
-# spans read by the trace reduction and the per-layer metrics
+# the program's spans and the driver's that label the trace's idle gaps
 SPAN_NAMES = (
     "session.step", "prefill_sched.select", "prefill.run_chunk", "decode.admit",
-    "decode_sched.select", "decode.step", "driver.submit", "driver.idle",
+    "decode_sched.select", "decode.step", "decode.launch", "decode.sync", "session.tokens",
+    "driver.submit", "driver.idle",
 )
 # traced seconds of the window (at most half of it), from 2 s (or a quarter) in:
 # long enough that a steady cell runs each of its step programs in them
@@ -68,14 +70,22 @@ class Cell:
     conf: Dict[str, Any]  # configs/<config>.json
     mix: Dict[str, Any]  # traffic/<traffic>.json
     cell: Dict[str, Any]  # cells/<name>.json
-    spec: modelspec.Spec
+    arch: ModuleType  # archs/<arch>.py of the configuration
+    spec: Any  # the architecture's Spec of the configuration
+
+
+def load_config(bench_dir: Path, name: str) -> Tuple[Dict[str, Any], ModuleType, Any]:
+    """``configs/<name>.json``, its architecture module and its Spec."""
+    conf = modelspec.load(bench_dir / "configs" / f"{name}.json")
+    arch = modelspec.arch_of(conf, bench_dir)
+    return conf, arch, arch.spec_of(conf)
 
 
 def load_cell(bench_dir: Path, name: str) -> Cell:
     cell = modelspec.load(bench_dir / "cells" / f"{name}.json")
-    conf = modelspec.load(bench_dir / "configs" / f"{cell['config']}.json")
+    conf, arch, spec = load_config(bench_dir, cell["config"])
     mix = modelspec.load(bench_dir / "traffic" / f"{cell['traffic']}.json")
-    return Cell(name, conf, mix, cell, modelspec.spec_of(conf))
+    return Cell(name, conf, mix, cell, arch, spec)
 
 
 def slo_limits(conf: Dict[str, Any], n_in: int) -> Tuple[float, float]:
@@ -104,30 +114,27 @@ class CompileCounter:
 class RunData:
     """What a per-layer metric reader may read."""
 
-    spec: modelspec.Spec
+    arch: ModuleType
+    spec: Any
     peak: Optional[Dict[str, float]]
     counted: List[Any]  # the counted requests (repro Request objects)
     events: Optional[List[Any]]  # obs events of the session (traced runs)
-    spans: Spans
-    window: Tuple[float, float]  # host perf_counter bounds of the window
     rounds: int  # session rounds inside the window
     trace: Optional[xplane.Reduction]
     stalls: List[Tuple[float, float]] = field(default_factory=list)  # profiler start/stop, session time
     timed: List[stats.Timed] = field(default_factory=list)  # counted requests, driver's clock
-    chunk_work: List[Tuple[int, int]] = field(default_factory=list)  # per traced chunk
-    decode_work: List[Tuple[int, int]] = field(default_factory=list)  # per traced step
 
 
 # ------------------------------------------------------------------ building
-def build_server(c: Cell, seed: int, device, trace: bool, spans: Optional[Spans]):
+def build_server(c: Cell, seed: int, device):
     """The server at the configuration's size, with weights from `seed`."""
     from repro.models import build_model
     from repro.serving.engine import DisaggServer, EngineConfig
 
-    cfg = modelspec.model_config(c.spec)
+    cfg = c.arch.model_config(c.spec)
     model = build_model(cfg)
     want = jax.tree.map(lambda x: (x.shape, str(x.dtype)), model.param_struct())
-    params = weights.make_params(c.spec, seed, device)
+    params = weights.make_params(c.arch, c.spec, seed, device)
     got = jax.tree.map(lambda x: (x.shape, str(x.dtype)), params)
     if got != want:
         raise ValueError("the benchmark's weight layout differs from the program's parameters")
@@ -137,25 +144,7 @@ def build_server(c: Cell, seed: int, device, trace: bool, spans: Optional[Spans]
         prefill_policy=eng["prefill_policy"], decode_policy=eng["decode_policy"],
         eos_token=NO_EOS,
     )
-    srv = DisaggServer(model, params, ecfg, device=device)
-    if spans is not None:
-        install_spans(srv, spans)
-    return srv
-
-
-def install_spans(srv, spans: Spans) -> None:
-    def chunk_rec(lr, take):
-        r = lr.req
-        return (r.prefix_cached_tokens + r.prefilled_tokens, take)
-
-    def decode_rec(batch, _key):
-        return [lr.req.seq_len - 1 for lr in batch]
-
-    spans.wrap(srv.prefill_sched, "select", "prefill_sched.select")
-    spans.wrap(srv.prefill, "run_chunk", "prefill.run_chunk", chunk_rec)
-    spans.wrap(srv.decode, "admit", "decode.admit")
-    spans.wrap(srv.decode_sched, "select", "decode_sched.select")
-    spans.wrap(srv.decode, "step", "decode.step", decode_rec)
+    return DisaggServer(model, params, ecfg, device=device)
 
 
 def warm_up(srv, c: Cell, device) -> jax.Array:
@@ -196,13 +185,12 @@ class Drive:
     late_max_s: float
     end: float  # driver time the loop stopped
     events: Optional[List[Any]]
-    window_host: Tuple[float, float]
     trace_dir: Optional[str]
     stalls: List[Tuple[float, float]] = field(default_factory=list)
 
 
-def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, spans: Optional[Spans],
-          bar: jax.Array, compiles: CompileCounter) -> Drive:
+def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, bar: jax.Array,
+          compiles: CompileCounter) -> Drive:
     """Drive the session open loop. Every time here is the driver's own
     clock (seconds since the session's clock was reset); each token's time
     is read when it reaches the driver's ``on_token`` callback."""
@@ -233,8 +221,6 @@ def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, spans: Optional[
 
     rec = TraceRecorder() if trace else None
     session = ServeSession(srv, on_token=on_token, trace=rec)
-    if spans is not None:
-        spans.wrap(session, "step", "session.step")
     t_lo = w0 + min(seconds / 4, 2.0)
     t_hi = t_lo + min(TRACE_S, seconds / 2)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -245,7 +231,6 @@ def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, spans: Optional[
     late: List[float] = []
     remaining = set()
     rounds = 0
-    host_w = [0.0, 0.0]
     stalls: List[Tuple[float, float]] = []  # session time spent starting/stopping the profiler
     pending = collections.deque(items)
     srv.reset_clock()
@@ -253,12 +238,7 @@ def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, spans: Optional[
     now = 0.0
     while True:
         now = clock()
-        if host_w[0] == 0.0 and now >= w0:
-            compiles.on = True
-            host_w[0] = time.perf_counter()
-        if host_w[1] == 0.0 and now >= w1:
-            compiles.on = False
-            host_w[1] = time.perf_counter()
+        compiles.on = w0 <= now < w1
         while pending and pending[0].due <= now:
             it = pending.popleft()
             ttft, tpot = slo_limits(c.conf, len(it.prompt))
@@ -283,12 +263,10 @@ def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, spans: Optional[
             stalls.append((s0, srv._now()))
             window_ann = jax.profiler.TraceAnnotation(xplane.WINDOW_SPAN)
             window_ann.__enter__()
-            spans.recording = True
             tracing = True
         if tracing and now >= t_hi:
             s0 = srv._now()
             (bar + 1).block_until_ready()
-            spans.recording = False
             window_ann.__exit__(None, None, None)
             jax.profiler.stop_trace()  # writes the trace: the loop stalls
             stalls.append((s0, srv._now()))
@@ -305,7 +283,6 @@ def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, spans: Optional[
             with jax.profiler.TraceAnnotation("driver.idle"):
                 time.sleep(min(0.001, max(0.0, nxt - clock())))
     if tracing:
-        spans.recording = False
         window_ann.__exit__(None, None, None)
         jax.profiler.stop_trace()
     for r in counted:
@@ -317,7 +294,7 @@ def drive(srv, c: Cell, seed: int, seconds: float, trace: bool, spans: Optional[
         late_p90_s=stats.percentile(late, 90) if late else 0.0,
         late_max_s=max(late) if late else 0.0, end=now,
         events=list(rec.events) if rec is not None else None,
-        window_host=(host_w[0], host_w[1]), trace_dir=trace_dir, stalls=stalls,
+        trace_dir=trace_dir, stalls=stalls,
     )
 
 
@@ -357,21 +334,8 @@ def describe(d: Drive) -> str:
 def load_reader(name: str, bench_dir: Path = HERE):
     """The ``read`` function of ``layer_metrics/<name>.py``, looked for
     beside the cell files first and then with the benchmark."""
-    path = bench_dir / "layer_metrics" / f"{name}.py"
-    if not path.exists():
-        path = HERE / "layer_metrics" / f"{name}.py"
-    if str(path.parent) not in sys.path:
-        sys.path.append(str(path.parent))
-    spec = importlib.util.spec_from_file_location(f"layer_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
-def trace_work(spans: Spans, s: modelspec.Spec):
-    chunk = [work.chunk(s, st, take) for st, take in spans.records.get("prefill.run_chunk", [])]
-    dec = [work.decode(s, pos) for pos in spans.records.get("decode.step", [])]
-    return chunk, dec
+    path = modelspec.find("layer_metrics", name, bench_dir)
+    return modelspec.module(path, f"layer_metric_{name}").read
 
 
 UNITS = {"ttft_p90_s": "s", "tpot_p90_ms": "ms", "itl_p90_ms": "ms", "slo_attainment": "%",
@@ -420,12 +384,11 @@ def run_cell(bench_dir: Path, name: str, seed: int, seconds: float, trace: bool,
     t_start = time.perf_counter() if t_start is None else t_start
     c = load_cell(bench_dir, name)
     compiles = CompileCounter()
-    spans = Spans() if trace else None
-    srv = build_server(c, seed, device, trace, spans)
+    srv = build_server(c, seed, device)
     bar = warm_up(srv, c, device)
     setup_s = time.perf_counter() - t_start
     log(f"bench: {name} seed {seed}: set-up {setup_s:.3f} s")
-    d = drive(srv, c, seed, seconds, trace, spans, bar, compiles)
+    d = drive(srv, c, seed, seconds, trace, bar, compiles)
     _, _, _, failed = request_times(d)
     log(f"bench: {len(d.counted)} counted requests, {failed} failed; "
         f"{d.rounds_in_window} rounds in the window; submission late p90 "
@@ -455,11 +418,9 @@ def run_cell(bench_dir: Path, name: str, seed: int, seconds: float, trace: bool,
             if device.platform == "tpu":
                 raise
             peak = None  # a CPU rehearsal: no roofline exists
-        chunk_w, dec_w = trace_work(spans, c.spec)
         run = RunData(
-            spec=c.spec, peak=peak, counted=d.counted, events=d.events, spans=spans,
-            window=d.window_host, rounds=d.rounds_in_window, trace=red,
-            chunk_work=chunk_w, decode_work=dec_w, stalls=d.stalls,
+            arch=c.arch, spec=c.spec, peak=peak, counted=d.counted, events=d.events,
+            rounds=d.rounds_in_window, trace=red, stalls=d.stalls,
             timed=[d.timed[r.rid] for r in d.counted],
         )
         for m, unit in per_layer:
@@ -502,20 +463,11 @@ def release(srv, *more) -> None:
     gc.collect()
 
 
-def compare(c: Cell, seed: int, prompts: Sequence[Sequence[int]],
-            served: Sequence[Sequence[int]], tokens: Optional[Sequence[Sequence[int]]] = None,
-            short: int = 0, log=lambda _m: None) -> Dict[str, Dict[str, float]]:
-    """The comparison that decides `correct`: the reference teacher-forced on
-    the served tokens, read at `tokens` (the served ones by default). Each
-    compared number beside its limit."""
-    t0 = time.perf_counter()
-    gaps = np.zeros((0,))
-    if prompts:
-        gaps = reference.score(c.spec, seed, prompts, served, tokens)
+def compare(c: Cell, gaps: np.ndarray, short: int = 0) -> Dict[str, Dict[str, float]]:
+    """The comparison that decides `correct`, from the sample's per-token
+    gaps to the reference's best logit: each compared number beside its
+    limit."""
     nums = gap_numbers(gaps)
-    log(f"bench: reference over {len(prompts)} requests, {gaps.size} served tokens, "
-        f"{time.perf_counter() - t0:.1f} s; widest gap {nums['served_token_gap']!r}, "
-        f"mean gap {nums['mean_served_gap']!r}")
     checks = {k: {"value": nums[k], "limit": float(v)} for k, v in c.cell["check"]["limits"].items()}
     checks["short_requests"] = {"value": float(short), "limit": 0.0}
     return checks
@@ -534,8 +486,14 @@ def check(c: Cell, seed: int, d: Drive, srv, log) -> Dict[str, Dict[str, float]]
     pick = choose_sample(d, seed, c.cell["check"].get("served_tokens"))
     release(srv)
     del srv
-    checks = compare(c, seed, [d.prompts[i] for i in pick], [d.outputs[i] for i in pick],
-                     short=short, log=log)
+    t0 = time.perf_counter()
+    served = [d.outputs[i] for i in pick]
+    gaps = reference.score(c.arch, c.spec, seed, [d.prompts[i] for i in pick], served)
+    nums = gap_numbers(gaps)
+    log(f"bench: reference over {len(pick)} requests, {gaps.size} served tokens, "
+        f"{time.perf_counter() - t0:.1f} s; widest gap {nums['served_token_gap']!r}, "
+        f"mean gap {nums['mean_served_gap']!r}")
+    checks = compare(c, gaps, short)
     for k, v in checks.items():
         log(f"check {k} {v['value']!r} limit {v['limit']!r}")
     return checks
@@ -546,17 +504,18 @@ def control_readings(c: Cell, seed: int, seconds: float, device) -> Dict[str, An
     the same sample: on the program's served tokens, and on the int8
     control's tokens put in their place (the reference computed with int8
     weights and activations, at the same positions)."""
-    srv = build_server(c, seed, device, False, None)
+    srv = build_server(c, seed, device)
     bar = warm_up(srv, c, device)
-    d = drive(srv, c, seed, seconds, False, None, bar, CompileCounter())
+    d = drive(srv, c, seed, seconds, False, bar, CompileCounter())
     pick = choose_sample(d, seed, c.cell["check"].get("served_tokens"))
     release(srv, bar)
     del srv
     prompts = [d.prompts[i] for i in pick]
     served = [d.outputs[i] for i in pick]
-    ctl = reference.control_tokens(c.spec, seed, prompts, served)
-    program = compare(c, seed, prompts, served)
-    control = compare(c, seed, prompts, served, ctl)
+    ctl = reference.control_tokens(c.arch, c.spec, seed, prompts, served)
+    ref = reference.teacher_logits(c.arch, c.spec, seed, prompts, served)
+    program = compare(c, reference.gaps(ref, served))
+    control = compare(c, reference.gaps(ref, ctl))
     return {"requests": len(pick), "tokens": sum(len(t) for t in served),
             "program": program, "program_correct": is_correct(program),
             "control": control, "control_correct": is_correct(control)}

@@ -1,5 +1,7 @@
 """Shared by the device-time readers: which traced programs are which
-serving step, and the roofline arithmetic over matched calls."""
+serving step, the work of the traced calls, and the roofline arithmetic
+over matched calls."""
+from _counters import stretch
 
 # program (XLA module) names of the engine's jitted steps
 CHUNK = ("jit_chunk_prefill_step",)
@@ -14,6 +16,20 @@ def times(run, names):
         if n in run.trace.modules:
             return run.trace.modules[n]
     return []
+
+
+def chunk_work(run):
+    """(FLOPs, bytes) of each chunk-prefill call of the traced stretch, from
+    the program's PREFILL_CHUNK counters (``start``, ``take``)."""
+    return [run.arch.chunk_work(run.spec, e.data["start"], e.data["take"])
+            for e in stretch(run, "prefill_chunk", "take")]
+
+
+def decode_work(run):
+    """(FLOPs, bytes) of each decode step of the traced stretch, from the
+    program's DECODE_STEP counters (each live lane's ``positions``)."""
+    return [run.arch.decode_work(run.spec, e.data["positions"])
+            for e in stretch(run, "decode_step", "positions")]
 
 
 def matched(run, names, works):

@@ -1,6 +1,5 @@
 """Mean host ms per session round in both schedulers' ``select`` calls
-(ROUND ``select_s``), in the traced stretch: the program's own twin of
-``sched_ms_per_round``."""
+(ROUND ``select_s``), in the traced stretch, as the program counts it."""
 from _counters import mean_ms, stretch
 
 

@@ -1,40 +1,26 @@
-"""A configuration file's model, read in the source's own vocabulary.
+"""A configuration file and the modules a cell finds by name.
 
 ``configs/<name>.json`` keeps the published ``config.json`` keys (cut where
-``reduced`` says). `Spec` is what the weights and the plain reference read;
-`model_config` maps it onto the program's ``ModelConfig`` for serving.
+``reduced`` says) and may name its architecture (``"arch"``; ``dense``
+where it does not). The architecture is the module ``archs/<arch>.py``: it
+reads the configuration into its frozen, hashable ``Spec`` (``spec_of``),
+maps it onto the program's ``ModelConfig`` (``model_config``), lays out the
+weights (``layer_shapes``, ``top_shapes``, ``param_tree``), gives the plain
+reference (``reference_embed``, ``reference_layer``, ``reference_head``)
+and counts a step's work (``chunk_work``, ``decode_work``). A module is
+looked for beside the cell files first, then with the benchmark.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
-from dataclasses import dataclass
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict
 
-
-@dataclass(frozen=True)
-class Spec:
-    name: str
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    ffn: int
-    vocab: int
-    rope_theta: float
-    eps: float
-    tied: bool
-    act: str
-    dtype: str
-
-    @property
-    def q_dim(self) -> int:
-        return self.heads * self.head_dim
-
-    @property
-    def kv_dim(self) -> int:
-        return self.kv_heads * self.head_dim
+HERE = Path(__file__).resolve().parent
+DEFAULT_ARCH = "dense"
 
 
 def load(path: Path) -> Dict[str, Any]:
@@ -42,32 +28,31 @@ def load(path: Path) -> Dict[str, Any]:
         return json.load(f)
 
 
-def spec_of(conf: Dict[str, Any]) -> Spec:
-    heads = conf["num_attention_heads"]
-    return Spec(
-        name=conf["name"],
-        layers=conf["num_hidden_layers"],
-        d=conf["hidden_size"],
-        heads=heads,
-        kv_heads=conf.get("num_key_value_heads", heads),
-        head_dim=conf.get("head_dim") or conf["hidden_size"] // heads,
-        ffn=conf["intermediate_size"],
-        vocab=conf["vocab_size"],
-        rope_theta=float(conf.get("rope_theta", 10000.0)),
-        eps=float(conf["rms_norm_eps"]),
-        tied=bool(conf["tie_word_embeddings"]),
-        act=conf["hidden_act"],
-        dtype=conf["torch_dtype"],
-    )
+def find(kind: str, name: str, bench_dir: Path = HERE) -> Path:
+    """``<bench_dir>/<kind>/<name>.py`` where it exists, else the benchmark's own."""
+    path = bench_dir / kind / f"{name}.py"
+    return path if path.exists() else HERE / kind / f"{name}.py"
 
 
-def model_config(spec: Spec):
-    """The program's ModelConfig for this spec (imports the program)."""
-    from repro.configs.base import ModelConfig
+def module(path: Path, key: str) -> ModuleType:
+    """The module at `path`, executed under `key` (its directory on the path,
+    for the helpers beside it)."""
+    if str(path.parent) not in sys.path:
+        sys.path.append(str(path.parent))
+    spec = importlib.util.spec_from_file_location(key, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[key] = mod  # a dataclass resolves its module while it is made
+    spec.loader.exec_module(mod)
+    return mod
 
-    return ModelConfig(
-        name=spec.name, family="dense", num_layers=spec.layers, d_model=spec.d,
-        num_heads=spec.heads, num_kv_heads=spec.kv_heads, head_dim=spec.head_dim,
-        d_ff=spec.ffn, vocab_size=spec.vocab, rope_theta=spec.rope_theta,
-        norm_eps=spec.eps, tie_embeddings=spec.tied, act=spec.act, dtype=spec.dtype,
-    )
+
+def arch(name: str, bench_dir: Path = HERE) -> ModuleType:
+    """The architecture module `name`, loaded once per file, so that every
+    cell of one architecture shares its ``Spec`` class and compiled programs."""
+    path = find("archs", name, bench_dir).resolve()
+    key = f"bench_arch:{path}"
+    return sys.modules.get(key) or module(path, key)
+
+
+def arch_of(conf: Dict[str, Any], bench_dir: Path = HERE) -> ModuleType:
+    return arch(conf.get("arch", DEFAULT_ARCH), bench_dir)

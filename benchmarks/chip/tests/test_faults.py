@@ -4,14 +4,17 @@ Each fault is planted in the program's serving path (never in the
 benchmark), and the rest of a run is driven as on the chip, at the fixture
 cell's size on the CPU: a served token altered where the decode step
 produces it; a decode step that returns its cache unchanged (the new
-token's KV dropped); a chunk-prefill step that returns its cache unchanged
-(the chunk's KV never reaches the cache). Each fault is run in both fixture
-cells: ``tiny.tiny-mix`` compares the widest gap, ``small.tiny-mix`` the
-mean gap, as the chip's cells do, and the number each cell compares must
-read over its limit."""
+token's KV dropped: the cache as it was before the call, since the step
+consumes the one it is given); a chunk-prefill step that returns its cache
+unchanged (the chunk's KV never reaches the cache). Each fault is run in
+the three fixture cells: ``tiny.tiny-mix`` and ``moe-tiny.tiny-mix`` (an
+architecture that exists only as fixture files) compare the widest gap,
+``small.tiny-mix`` the mean gap, as the chip's cells do, and the number
+each cell compares must read over its limit."""
 import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,7 +22,7 @@ import harness
 from conftest import HERE
 
 FIXTURES = HERE / "fixtures"
-CELLS = ["tiny.tiny-mix", "small.tiny-mix"]
+CELLS = ["tiny.tiny-mix", "small.tiny-mix", "moe-tiny.tiny-mix"]
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -49,8 +52,9 @@ def _state_unchanged(name):
 
         @functools.wraps(good)
         def bad(*args):
+            before = jax.tree.map(jnp.copy, args[cache_arg])
             logits, _ = good(*args)
-            return logits, args[cache_arg]
+            return logits, before
 
         monkeypatch.setattr(engine, name, bad)
 

@@ -1,6 +1,7 @@
-"""CPU rehearsal of a whole run: the harness's driver end to end at a
-fixture cell found only by its files, through the function entry; and the
-command line's refusals."""
+"""CPU rehearsal of a whole run: the harness's driver end to end at fixture
+cells found only by their files (one of them of an architecture that exists
+only as fixture files), through the function entry; and the command line's
+refusals."""
 import json
 import os
 import shutil
@@ -16,8 +17,8 @@ from conftest import BENCH, HERE, ROOT
 FIXTURES = HERE / "fixtures"
 CELL = "tiny.tiny-mix"
 END_TO_END = ("ttft_p90_s", "tpot_p90_ms", "itl_p90_ms", "slo_attainment", "decode_tok_s", "setup_s")
-PER_LAYER = [("queue_wait_p90_ms", "ms"), ("sched_ms_per_round", "ms"), ("token_gap_p90_ms", "ms"),
-             ("device_idle_share", "%"), ("fixture_rounds", "1")]
+PER_LAYER = [("queue_wait_p90_ms", "ms"), ("select_ms_per_round", "ms"), ("token_gap_p90_ms", "ms"),
+             ("device_idle_share", "%"), ("decode_step_roofline", "%"), ("fixture_rounds", "1")]
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -25,15 +26,16 @@ def no_compile_cache():
     jax.config.update("jax_enable_compilation_cache", False)
 
 
-def run(seed, trace=False, seconds=2.0):
+def run(seed, trace=False, seconds=2.0, cell=CELL):
     lines = []
-    res = harness.run_cell(FIXTURES, CELL, seed, seconds, trace, jax.devices()[0],
+    res = harness.run_cell(FIXTURES, cell, seed, seconds, trace, jax.devices()[0],
                            PER_LAYER if trace else (), log=lines.append)
     return res, lines
 
 
-def test_run_is_correct_and_reports_every_end_to_end_metric():
-    res, lines = run(2**31 + 3)
+@pytest.mark.parametrize("cell", [CELL, "moe-tiny.tiny-mix"])
+def test_run_is_correct_and_reports_every_end_to_end_metric(cell):
+    res, lines = run(2**31 + 3, cell=cell)
     assert res["correct"] is True
     assert res["attempted"] == round(8.0 * 2.0) and res["failed"] == 0
     assert set(res["metrics"]) == set(END_TO_END)
@@ -71,24 +73,12 @@ def test_same_seed_same_work_other_seed_same_sizes():
 def test_traced_run_reads_the_per_layer_metrics_it_can():
     res, _ = run(11, trace=True)
     assert res["correct"] is True
-    # the CPU has no device plane: device metrics stay silent, host ones read
-    assert {"queue_wait_p90_ms", "sched_ms_per_round", "token_gap_p90_ms",
+    # the CPU has no device plane and no peaks: device metrics stay silent,
+    # host ones read
+    assert {"queue_wait_p90_ms", "select_ms_per_round", "token_gap_p90_ms",
             "fixture_rounds"} <= set(res["metrics"])
-    assert "device_idle_share" not in res["metrics"]
+    assert not {"device_idle_share", "decode_step_roofline"} & set(res["metrics"])
     assert res["metrics"]["fixture_rounds"]["value"] > 0
-
-
-def test_missing_wrapped_method_leaves_its_metric_out(monkeypatch):
-    from repro.policies.decode import SlackDecodeScheduler
-
-    monkeypatch.delattr(SlackDecodeScheduler, "select")
-    monkeypatch.setattr(SlackDecodeScheduler, "pick", lambda *a: None, raising=False)
-    spans = harness.Spans()
-    cell = harness.load_cell(FIXTURES, CELL)
-    harness.build_server(cell, 1, None, True, spans)
-    assert "decode_sched.select" in spans.missing
-    run = harness.RunData(cell.spec, None, [], None, spans, (0.0, 1.0), 3, None)
-    assert harness.load_reader("sched_ms_per_round")(run) is None
 
 
 def _cli(args, cwd=ROOT, env_extra=None):

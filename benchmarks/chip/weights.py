@@ -1,53 +1,39 @@
 """Seeded weights, made on the device by the benchmark.
 
 The served weights come from one jitted call (`make_params`) in the
-configuration's dtype, laid out as the program's parameter tree. The plain
+configuration's dtype, laid out as the program's parameter tree by the
+configuration's architecture module (``arch.param_tree``). The plain
 reference regenerates any one layer alone (`layer_params`) with the same
-values, so it needs nothing the program holds. Every leaf is uniform with
-the spread the program's own initialiser gives it (standard deviation
-1/sqrt(fan_in) for projections, 0.02 for the embedding); norm gains are
-small and non-zero so that a path that drops them shows. Values are drawn
-per (leaf, layer) from keys folded from the seed, with integer key
-arithmetic and one scale, so a layer drawn alone equals its slice of the
-stacked draw bit for bit.
+values, so it needs nothing the program holds.
+
+The architecture names each leaf with its shape and spread
+(``arch.layer_shapes`` / ``arch.top_shapes``: path -> (shape, std), or
+(shape, std, dtype) for a leaf the program keeps in another dtype than the
+configuration's). Every leaf is uniform with that standard deviation (the
+program's own initialiser gives projections 1/sqrt(fan_in) and the
+embedding 0.02); norm gains are small and non-zero so that a path that
+drops them shows. Values are drawn per (leaf, layer) from keys folded from
+the seed, the leaf's index being its place in ``layer_shapes`` (the top
+leaves follow the longest layer's), with integer key arithmetic and one
+scale, so a layer drawn alone equals its slice of the stacked draw bit for
+bit. Layers with the same leaves are drawn stacked, in one vmap.
 """
 from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from modelspec import Spec
 
 NORM_STD = 0.05
 EMBED_STD = 0.02
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32, "float16": jnp.float16}
 
-
-def layer_shapes(s: Spec) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    """Per-layer leaf -> (shape, std), as "group/name" paths."""
-    return {
-        "attn/wq": ((s.d, s.q_dim), 1 / math.sqrt(s.d)),
-        "attn/wk": ((s.d, s.kv_dim), 1 / math.sqrt(s.d)),
-        "attn/wv": ((s.d, s.kv_dim), 1 / math.sqrt(s.d)),
-        "attn/wo": ((s.q_dim, s.d), 1 / math.sqrt(s.q_dim)),
-        "mlp/w_gate": ((s.d, s.ffn), 1 / math.sqrt(s.d)),
-        "mlp/w_up": ((s.d, s.ffn), 1 / math.sqrt(s.d)),
-        "mlp/w_down": ((s.ffn, s.d), 1 / math.sqrt(s.ffn)),
-        "pre_attn_norm": ((s.d,), NORM_STD),
-        "pre_mlp_norm": ((s.d,), NORM_STD),
-    }
-
-
-def top_shapes(s: Spec) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    out = {"embed": ((s.vocab, s.d), EMBED_STD), "final_norm": ((s.d,), NORM_STD)}
-    if not s.tied:
-        out["lm_head"] = ((s.d, s.vocab), 1 / math.sqrt(s.d))
-    return out
+# one layer's leaves as a hashable tuple: ((path, (shape, std[, dtype])), ...)
+Leaves = Tuple[Tuple[str, tuple], ...]
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -65,7 +51,8 @@ def _draw(key, idx, layer, shape, std, dtype):
     return (jax.random.uniform(k, shape, jnp.float32, -1.0, 1.0) * a).astype(dtype)
 
 
-def _nest(flat: Dict[str, jax.Array]) -> Dict:
+def nest(flat: Dict[str, jax.Array]) -> Dict:
+    """"group/name" paths -> nested dicts."""
     out: Dict = {}
     for path, x in flat.items():
         *groups, name = path.split("/")
@@ -76,55 +63,70 @@ def _nest(flat: Dict[str, jax.Array]) -> Dict:
     return out
 
 
-def _layer(s: Spec, key, layer, dtype) -> Dict[str, jax.Array]:
+def _leaves(leaves: Leaves, key, layer, dtype, base: int = 0) -> Dict[str, jax.Array]:
     return {
-        path: _draw(key, i, layer, shape, std, dtype)
-        for i, (path, (shape, std)) in enumerate(layer_shapes(s).items())
+        path: _draw(key, base + i, layer, shape, std, DTYPES[own[0]] if own else dtype)
+        for i, (path, (shape, std, *own)) in enumerate(leaves)
     }
 
 
-def _top(s: Spec, key, dtype) -> Dict[str, jax.Array]:
-    base = len(layer_shapes(s))
-    return {
-        path: _draw(key, base + i, 0, shape, std, dtype)
-        for i, (path, (shape, std)) in enumerate(top_shapes(s).items())
-    }
+def layer_leaves(arch, s, layer: int) -> Leaves:
+    return tuple(arch.layer_shapes(s, layer).items())
 
 
-@partial(jax.jit, static_argnums=(0,))
-def _params(s: Spec, key) -> Dict:
+def groups(arch, s) -> List[Tuple[Leaves, Tuple[int, ...]]]:
+    """The layers grouped by their leaves, in order of each group's first
+    layer: (leaves, layer indices)."""
+    out: Dict[Leaves, List[int]] = {}
+    for layer in range(s.layers):
+        out.setdefault(layer_leaves(arch, s, layer), []).append(layer)
+    return [(k, tuple(v)) for k, v in out.items()]
+
+
+def _top(arch, s, key, dtype) -> Dict[str, jax.Array]:
+    base = max(len(leaves) for leaves, _ in groups(arch, s))
+    return _leaves(tuple(arch.top_shapes(s).items()), key, 0, dtype, base)
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _params(arch, s, key) -> Dict:
     dt = DTYPES[s.dtype]
-    layers = jax.vmap(lambda l: _layer(s, key, l, dt))(jnp.arange(s.layers, dtype=jnp.uint32))
-    top = _top(s, key, dt)
-    top["layers"] = _nest(layers)
-    return top
+    layers = [
+        (idx, jax.vmap(lambda l, leaves=leaves: _leaves(leaves, key, l, dt))(
+            jnp.asarray(idx, jnp.uint32)))
+        for leaves, idx in groups(arch, s)
+    ]
+    return arch.param_tree(s, layers, _top(arch, s, key, dt))
 
 
-def make_params(s: Spec, seed: int, device=None) -> Dict:
-    """The served parameter tree, on `device`, in the configuration's dtype."""
+def make_params(arch, s, seed: int, device=None) -> Dict:
+    """The served parameter tree, on `device`, in the configuration's dtype.
+    ``arch.param_tree(s, layers, top)`` arranges it: `layers` lists, per
+    group of layers with the same leaves, (layer indices, flat leaves
+    stacked over those layers)."""
     key = seed_key(seed)
     if device is not None:
         key = jax.device_put(key, device)
-    return _params(s, key)
+    return _params(arch, s, key)
 
 
-@partial(jax.jit, static_argnums=(0,))
-def _layer_one(s: Spec, key, layer) -> Dict[str, jax.Array]:
-    return _layer(s, key, layer, DTYPES[s.dtype])
+@partial(jax.jit, static_argnums=(0, 1))
+def _layer_one(s, leaves: Leaves, key, layer) -> Dict[str, jax.Array]:
+    return _leaves(leaves, key, layer, DTYPES[s.dtype])
 
 
-@partial(jax.jit, static_argnums=(0,))
-def _top_one(s: Spec, key) -> Dict[str, jax.Array]:
-    return _top(s, key, DTYPES[s.dtype])
+@partial(jax.jit, static_argnums=(0, 1))
+def _top_one(arch, s, key) -> Dict[str, jax.Array]:
+    return _top(arch, s, key, DTYPES[s.dtype])
 
 
-# Both return the configuration's dtype, as served: a conversion to float32
-# inside the same program could be folded away with the rounding before it.
-def layer_params(s: Spec, seed: int, layer: int) -> Dict[str, jax.Array]:
+# Both return the leaves' dtypes, as served: a conversion to float32 inside
+# the same program could be folded away with the rounding before it.
+def layer_params(arch, s, seed: int, layer: int) -> Dict[str, Any]:
     """Layer `layer`'s leaves, flat ("attn/wq", ...), as served."""
-    return _layer_one(s, seed_key(seed), jnp.uint32(layer))
+    return _layer_one(s, layer_leaves(arch, s, layer), seed_key(seed), jnp.uint32(layer))
 
 
-def top_params(s: Spec, seed: int) -> Dict[str, jax.Array]:
-    """Embedding, final norm and (untied) head, as served."""
-    return _top_one(s, seed_key(seed))
+def top_params(arch, s, seed: int) -> Dict[str, jax.Array]:
+    """The leaves outside the layers (embedding, final norm, head), as served."""
+    return _top_one(arch, s, seed_key(seed))
